@@ -1,9 +1,5 @@
-"""Multi-device solver benchmark: the mesh-aware SolverPlan across forced
-host device counts.
-
-JAX pins the device count at first init, so the parent process spawns one
-child per device count (``XLA_FLAGS=--xla_force_host_platform_device_count``)
-and merges their rows:
+"""Multi-device solver benchmark: the mesh-aware SolverPlan across device
+counts.
 
     PYTHONPATH=src python -m benchmarks.bench_multidevice [--smoke]
         [--out BENCH_multidevice.json]
@@ -14,13 +10,20 @@ one all-gather per round) and the warm ``plan.solve``/``solve_batched``
 wall-clock at a fixed iteration count.  ``d=1`` additionally records the
 meshless plan as the no-collectives baseline.
 
-Emits ``BENCH_multidevice.json`` (schema ``bench_multidevice/v1``).  NOTE:
-on a CPU host the "devices" are XLA host-platform threads, so the rows
-track the COST of distribution (collective per round + replicated state)
-rather than a speedup — the tripwire is that semantics hold (identical
-iteration counts, see ``iters_equal``) and that per-round collective
-overhead stays bounded.  On a real TPU/GPU mesh the same rows measure
-genuine strong scaling of the sharded tables/operands.
+How the device counts are made depends on the platform:
+
+* On a TPU host one process holds every chip (a chip belongs to one
+  process) and builds sub-meshes of the first d chips, for each d in
+  ``DEVICE_COUNTS`` that the host has.
+* Elsewhere JAX pins the device count at first init, so the parent spawns
+  one child per forced host device count
+  (``XLA_FLAGS=--xla_force_host_platform_device_count``) and merges their
+  rows.  On a CPU host those "devices" are XLA host-platform threads, so
+  the rows track the COST of distribution (collective per round +
+  replicated state) rather than a speedup — the tripwire is that
+  semantics hold (identical iteration counts, see ``iters_equal``).
+
+Emits ``BENCH_multidevice.json`` (schema ``bench_multidevice/v1``).
 """
 from __future__ import annotations
 
@@ -39,21 +42,19 @@ METHODS = ("hbmc", "bmc")
 
 
 # ---------------------------------------------------------------------------
-# Child: runs under a forced device count, writes its rows to --child-out.
+# Rows for one device count: a mesh over the given devices.
 # ---------------------------------------------------------------------------
 
-def _child(args) -> None:
+def _rows(args, devices) -> list[dict]:
     import jax
-
-    jax.config.update("jax_enable_x64", True)
     import jax.numpy as jnp
     import numpy as np
+    from jax.sharding import Mesh
 
     from repro.core.matrices import laplace_2d
     from repro.core.plan import build_plan
 
-    n_dev = args.devices
-    assert len(jax.devices()) == n_dev, (len(jax.devices()), n_dev)
+    n_dev = len(devices)
     if args.smoke:
         a, bs, w = laplace_2d(16, 14), 8, 4
     else:
@@ -62,7 +63,7 @@ def _child(args) -> None:
     rng = np.random.default_rng(42)
     b1 = rng.normal(size=n)
     bb = rng.normal(size=(n, max(BATCHES)))
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = Mesh(np.array(devices), ("data",))
 
     def time_best(fn, reps):
         fn()                                   # compile + warm caches
@@ -117,12 +118,22 @@ def _child(args) -> None:
                 "solve_us": round(rep.solve_seconds * 1e6, 1),
                 "iterations": its,
             })
+    return rows
+
+
+def _child(args) -> None:
+    """Runs under a forced host device count; writes its rows to
+    ``--child-out``."""
+    import jax
+
+    n_dev = args.devices
+    assert len(jax.devices()) == n_dev, (len(jax.devices()), n_dev)
     with open(args.child_out, "w") as f:
-        json.dump(rows, f)
+        json.dump(_rows(args, jax.devices()), f)
 
 
 # ---------------------------------------------------------------------------
-# Parent: one child per device count, merged doc + derived breakdown.
+# Parent: rows per device count, merged doc + derived breakdown.
 # ---------------------------------------------------------------------------
 
 def _derived(rows):
@@ -165,10 +176,54 @@ def main() -> None:
     args.maxiter = args.maxiter or (50 if args.smoke else 120)
     args.reps = args.reps or (3 if args.smoke else 10)
 
+    import jax
+
+    jax.config.update("jax_enable_x64", True)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.child_out is not None:
         _child(args)
         return
 
+    platform = jax.default_backend()
+    if platform == "tpu":
+        devs = jax.devices()
+        rows = []
+        for n_dev in DEVICE_COUNTS:
+            if n_dev <= len(devs):
+                print(f"[bench_multidevice] devices={n_dev} ...", flush=True)
+                rows.extend(_rows(args, devs[:n_dev]))
+    else:
+        rows = _spawn_children(args)
+
+    doc = {
+        "schema": "bench_multidevice/v1",
+        "platform": platform,
+        "smoke": bool(args.smoke),
+        "maxiter": args.maxiter,
+        "device_counts": sorted({r["n_devices"] for r in rows}),
+        "results": rows,
+        "derived": _derived(rows),
+    }
+    with open(args.out, "w") as f:
+        json.dump(doc, f, indent=2)
+        f.write("\n")
+
+    hdr = (f"{'devices':>7s} {'mesh':>5s} {'method':7s} {'B':>2s} "
+           f"{'apply us':>10s} {'solve us':>12s} {'iters':>6s}")
+    print(hdr)
+    for r in rows:
+        print(f"{r['n_devices']:7d} {str(r['mesh']):>5s} {r['method']:7s} "
+              f"{r['B']:2d} {r['apply_us']:10.1f} {r['solve_us']:12.0f} "
+              f"{r['iterations']:6d}")
+    for k, v in doc["derived"].items():
+        flag = "OK" if v["iters_equal"] else "MISMATCH"
+        print(f"  {k:12s} iters {flag}  apply {v['apply_us_by_devices']}")
+    print(f"wrote {args.out}")
+
+
+def _spawn_children(args) -> list[dict]:
+    """One child process per forced host device count (CPU only)."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     rows = []
     for n_dev in DEVICE_COUNTS:
@@ -193,33 +248,7 @@ def main() -> None:
         with open(child_out) as f:
             rows.extend(json.load(f))
         os.unlink(child_out)
-
-    import jax  # parent only needs the platform tag
-
-    doc = {
-        "schema": "bench_multidevice/v1",
-        "platform": jax.default_backend(),
-        "smoke": bool(args.smoke),
-        "maxiter": args.maxiter,
-        "device_counts": list(DEVICE_COUNTS),
-        "results": rows,
-        "derived": _derived(rows),
-    }
-    with open(args.out, "w") as f:
-        json.dump(doc, f, indent=2)
-        f.write("\n")
-
-    hdr = (f"{'devices':>7s} {'mesh':>5s} {'method':7s} {'B':>2s} "
-           f"{'apply us':>10s} {'solve us':>12s} {'iters':>6s}")
-    print(hdr)
-    for r in rows:
-        print(f"{r['n_devices']:7d} {str(r['mesh']):>5s} {r['method']:7s} "
-              f"{r['B']:2d} {r['apply_us']:10.1f} {r['solve_us']:12.0f} "
-              f"{r['iterations']:6d}")
-    for k, v in doc["derived"].items():
-        flag = "OK" if v["iters_equal"] else "MISMATCH"
-        print(f"  {k:12s} iters {flag}  apply {v['apply_us_by_devices']}")
-    print(f"wrote {args.out}")
+    return rows
 
 
 if __name__ == "__main__":

@@ -66,7 +66,7 @@ def _operator(a):
     vals_d, cols_d = jnp.asarray(vals), jnp.asarray(cols)
 
     def spmv(x):
-        return jnp.einsum("rk,rk->r", vals_d, x[cols_d])
+        return jnp.einsum("kr,kr->r", vals_d, x[cols_d])
 
     b = np.random.default_rng(0).normal(size=a.shape[0])
     sysd_b = _order_system(sp.csr_matrix(a), b, KNOBS["method"],
@@ -204,6 +204,8 @@ def main() -> None:
                     help="tiny problem, fewer iterations/requests (CI)")
     ap.add_argument("--out", default="BENCH_robustness.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         problems = [("lap2d_12", laplace_2d(12, 12), 100)]

@@ -193,6 +193,8 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_serve.json")
     ap.add_argument("--requests", type=int, default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         a, name = laplace_2d(12, 12), "lap2d_12"

@@ -142,7 +142,7 @@ def _seed_pack_ell(a):
         lo, hi = a.indptr[r], a.indptr[r + 1]
         cols[r, :hi - lo] = a.indices[lo:hi]
         vals[r, :hi - lo] = a.data[lo:hi]
-    return cols, vals
+    return cols.T.copy(), vals.T.copy()
 
 
 def _legacy_setup(a, method):
@@ -402,6 +402,8 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=None)
     ap.add_argument("--maxiter", type=int, default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     reps = args.reps or (2 if args.smoke else 5)
     maxiter = args.maxiter or (10 if args.smoke else 40)

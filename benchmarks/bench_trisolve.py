@@ -224,6 +224,8 @@ def main() -> None:
     ap.add_argument("--maxiter", type=int, default=None)
     ap.add_argument("--reps", type=int, default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     maxiter = args.maxiter or (10 if args.smoke else 60)
     reps = args.reps or (3 if args.smoke else 10)

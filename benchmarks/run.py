@@ -28,6 +28,8 @@ def main() -> None:
                                                          "bench"))
     ap.add_argument("--dryrun-dir", default="results/dryrun")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import paper_tables as T
 

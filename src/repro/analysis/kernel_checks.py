@@ -5,20 +5,22 @@ their packed operands that, when violated, fail only at dispatch time (or
 worse, silently on TPU where an out-of-tile index wraps).  These checks
 prove them on the host before any ``pallas_call``:
 
-  * **shape/grid consistency** — the fused trisolve grid is ``(2S,)`` with
-    per-step BlockSpecs ``(1, R, K)`` over ``(2S, R, K)`` operands, the
-    SELL grid ``(ns/t,)`` with slice-tile BlockSpecs; block shapes must
-    divide the (padded) operand shapes exactly;
+  * **shape/grid consistency** — the fused trisolve runs ``2S`` steps
+    over ``(2S, R, K)`` operands (two mirrored sweeps), the SELL kernel
+    takes ``(n_slices, K, w)`` operands;
   * **index-map bounds** — every gather index a kernel can read with a
     nonzero value must land inside the VMEM-resident vector (the
     ``fill_value=0`` guard is only correct when paired with zero values);
-  * **VMEM footprint** — the per-grid-step working set (blocked operands +
-    resident vectors, input/output-aliased buffers counted once) against a
-    per-core budget, with the estimate returned so callers can rescale.
+  * **VMEM footprint** — the kernel's working set (the resident vector
+    and the per-step blocks, both double-buffered by the pipeline, plus
+    the gather's staging block) against the scoped VMEM the kernels
+    request, with the estimate returned so callers can rescale.
 
 Checks return :class:`repro.analysis.schedule.Violation` lists (empty =
 clean) so the CLI prints one witness format for schedule and kernel
-findings alike.  VMEM size per the Pallas TPU guide: ~16 MiB/core.
+findings alike.  The budget is the ``vmem_limit_bytes`` every kernel asks
+the compiler for (``kernels.config.VMEM_LIMIT_BYTES``; a v5e core has
+128 MiB of VMEM).
 """
 from __future__ import annotations
 
@@ -26,41 +28,50 @@ import numpy as np
 
 from .schedule import MAX_VIOLATIONS, ScheduleError, Violation
 
-#: Per-core VMEM budget (bytes).  TPU VMEM is ~16 MiB/core; the default
-#: leaves headroom for the compiler's own buffers.
-VMEM_BUDGET_BYTES = 14 * 2**20
+#: Scoped VMEM per kernel (bytes) — equal to
+#: ``kernels.config.VMEM_LIMIT_BYTES``, the limit the kernels request.
+VMEM_BUDGET_BYTES = 64 * 2**20
 
-#: Mirrors kernels.config.DEFAULT_SLICE_TILE without importing jax.
+#: Mirror kernels.config (LANES, SUBLANES, DEFAULT_SLICE_TILE) without
+#: importing jax.
+LANES, SUBLANES = 128, 8
 DEFAULT_SLICE_TILE = 256
 
 
-def trisolve_fused_vmem_bytes(s2: int, r: int, k: int, itemsize: int,
-                              batch: int = 1) -> int:
-    """Working set of one fused-trisolve grid step, in bytes.
+def trisolve_fused_vmem_bytes(s2: int, r: int, k: int,
+                              itemsize: int) -> int:
+    """Working set of the fused-trisolve kernel, in bytes.
 
-    Blocked per step: cols (1, R, K) int32 + vals (1, R, K) dtype +
-    dinv (1, R) dtype.  Resident across steps: q (S, R[, B]) dtype and the
-    in/out-aliased y (S*R[, B]) dtype (counted once — aliasing means one
-    buffer).
+    Resident: the output y (S*R).  Per grid step: one lane tile (T lanes)
+    of cols (int32) and vals, K planes each, plus its dinv and q rows.
+    Both are double-buffered by the pipeline.  Scratch: the (wc, wc)
+    staging block of the gather.  T and wc follow the kernel: rows of
+    wc = 128 lanes when R allows, tiles of 8 such rows when the round has
+    a multiple of 8, else the whole round.
     """
-    s = s2 // 2
-    per_step = r * k * (4 + itemsize) + r * itemsize
-    resident = s * r * batch * itemsize * 2          # q + aliased y
-    return per_step + resident
+    wc = LANES if r % LANES == 0 else r
+    rows = r // wc
+    t = (SUBLANES if rows % SUBLANES == 0 else rows) * wc
+    resident = (s2 // 2) * r * itemsize
+    per_step = t * k * (4 + itemsize) + 2 * t * itemsize
+    return 2 * (resident + per_step) + wc * wc * itemsize
 
 
-def sell_spmv_vmem_bytes(t: int, k: int, w: int, n_pad: int, itemsize: int,
-                         batch: int = 1) -> int:
-    """Working set of one SELL SpMV grid step, in bytes: vals + cols tiles
-    (t, K, w), the resident x (n_pad[, B]) and the output tile
-    (t, w[, B])."""
-    tiles = t * k * w * (4 + itemsize)
-    resident = n_pad * batch * itemsize
-    out_tile = t * w * batch * itemsize
-    return tiles + resident + out_tile
+def sell_spmv_vmem_bytes(t: int, k: int, w: int, n_pad: int,
+                         itemsize: int) -> int:
+    """Working set of the SELL SpMV kernel, in bytes: the resident x
+    (n_pad, padded to whole 128-lane rows), per grid step the cols and
+    vals planes of ``t`` slices (rounded up to whole (8, 128) tiles) and
+    the output tile — all double-buffered — plus the (128, 128) staging
+    block of the gather."""
+    rows = -(-t * w // LANES)
+    step_rows = -(-rows // SUBLANES) * SUBLANES * LANES
+    resident = -(-n_pad // LANES) * LANES * itemsize
+    per_step = step_rows * (k * (4 + itemsize) + itemsize)
+    return 2 * (resident + per_step) + LANES * LANES * itemsize
 
 
-def check_trisolve_fused(cols, vals, dinv, batch: int = 1,
+def check_trisolve_fused(cols, vals, dinv,
                          vmem_budget: int = VMEM_BUDGET_BYTES,
                          where: str = "kernel/hbmc_trisolve_fused"
                          ) -> list[Violation]:
@@ -111,19 +122,17 @@ def check_trisolve_fused(cols, vals, dinv, batch: int = 1,
             detail=f"vals[{g},{t},{k}] != 0 on the fill_value pad "
                    f"position — the guarded read would drop a real "
                    f"contribution"))
-    need = trisolve_fused_vmem_bytes(s2, r_, k_, vals.dtype.itemsize,
-                                     batch=batch)
+    need = trisolve_fused_vmem_bytes(s2, r_, k_, vals.dtype.itemsize)
     if need > vmem_budget:
         out.append(Violation(
             kind="vmem-budget", where=where,
-            detail=f"per-step working set ~{need / 2**20:.1f} MiB exceeds "
+            detail=f"working set ~{need / 2**20:.1f} MiB exceeds "
                    f"the {vmem_budget / 2**20:.1f} MiB budget (S={s2 // 2}, "
-                   f"R={r_}, K={k_}, B={batch}); shard rounds across "
-                   f"devices or reduce the lane tile"))
+                   f"R={r_}, K={k_}); shard rounds across devices"))
     return out[:MAX_VIOLATIONS]
 
 
-def check_sell_spmv(vals, cols, n_pad: int, batch: int = 1,
+def check_sell_spmv(vals, cols, n_pad: int,
                     slice_tile: int = DEFAULT_SLICE_TILE,
                     vmem_budget: int = VMEM_BUDGET_BYTES,
                     where: str = "kernel/sell_spmv") -> list[Violation]:
@@ -149,9 +158,9 @@ def check_sell_spmv(vals, cols, n_pad: int, batch: int = 1,
             kind="index-dtype", where=where,
             detail=f"cols dtype {cols.dtype} is not integral"))
         return out
-    # the kernel pads the slice axis to a multiple of t = min(tile, ns),
-    # so the grid always divides; what CAN go wrong is a live gather index
-    # outside the resident x (fill_value masks it to 0 — a dropped term)
+    # the kernel pads the rows to whole grid tiles, so the grid always
+    # divides; what CAN go wrong is a live gather index outside the
+    # resident x (read as 0 — a dropped term)
     t = min(slice_tile, n_slices)
     live = vals != 0
     bad = live & ((cols < 0) | (cols >= n_pad))
@@ -162,20 +171,18 @@ def check_sell_spmv(vals, cols, n_pad: int, batch: int = 1,
             detail=f"cols[{s},{k},{w}] = {int(cols[s, k, w])} with a "
                    f"nonzero value, outside x's domain [0, {n_pad}) — the "
                    f"fill_value guard would silently drop this term"))
-    need = sell_spmv_vmem_bytes(t, k_, w_, n_pad, vals.dtype.itemsize,
-                                batch=batch)
+    need = sell_spmv_vmem_bytes(t, k_, w_, n_pad, vals.dtype.itemsize)
     if need > vmem_budget:
         out.append(Violation(
             kind="vmem-budget", where=where,
-            detail=f"per-step working set ~{need / 2**20:.1f} MiB exceeds "
+            detail=f"working set ~{need / 2**20:.1f} MiB exceeds "
                    f"the {vmem_budget / 2**20:.1f} MiB budget "
-                   f"(tile={t}, K={k_}, w={w_}, n_pad={n_pad}, B={batch}); "
+                   f"(tile={t}, K={k_}, w={w_}, n_pad={n_pad}); "
                    f"lower slice_tile or shard the slice axis"))
     return out[:MAX_VIOLATIONS]
 
 
-def check_plan_kernels(plan, batch: int = 1,
-                       vmem_budget: int = VMEM_BUDGET_BYTES
+def check_plan_kernels(plan, vmem_budget: int = VMEM_BUDGET_BYTES
                        ) -> list[Violation]:
     """Run the static kernel checks a plan's backend selection implies.
 
@@ -187,19 +194,17 @@ def check_plan_kernels(plan, batch: int = 1,
     out: list[Violation] = []
     if plan.backend == "pallas" and plan.layout == "round_major":
         t = plan._precond.tables
-        out += check_trisolve_fused(t.cols, t.vals, t.dinv, batch=batch,
+        out += check_trisolve_fused(t.cols, t.vals, t.dinv,
                                     vmem_budget=vmem_budget)
     if plan.spmv_backend == "pallas":
         out += check_sell_spmv(plan._spmv_vals, plan._spmv_cols,
-                               n_pad=int(plan.slab_m), batch=batch,
+                               n_pad=int(plan.slab_m),
                                vmem_budget=vmem_budget)
     return out
 
 
-def assert_plan_kernels(plan, batch: int = 1,
-                        vmem_budget: int = VMEM_BUDGET_BYTES,
+def assert_plan_kernels(plan, vmem_budget: int = VMEM_BUDGET_BYTES,
                         context: str = "") -> None:
-    violations = check_plan_kernels(plan, batch=batch,
-                                    vmem_budget=vmem_budget)
+    violations = check_plan_kernels(plan, vmem_budget=vmem_budget)
     if violations:
         raise ScheduleError(violations, context=context)

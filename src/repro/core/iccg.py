@@ -18,7 +18,6 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -69,16 +68,27 @@ def status_name(code) -> str:
 
 
 def spmv_ell(vals: jax.Array, cols: jax.Array, x: jax.Array) -> jax.Array:
-    """(n, K) row-major ELL SpMV: y_i = sum_k vals[i,k] * x[cols[i,k]]."""
-    return jnp.einsum("rk,rk->r", vals, x[cols])
+    """(K, n) column-major ELL SpMV: y_i = sum_k vals[k,i] * x[cols[k,i]]."""
+    return jnp.einsum("kr,kr->r", vals, x[cols])
 
 
+def k_sum(vals: jax.Array, g: jax.Array, axis: int) -> jax.Array:
+    """``sum_k vals * g`` over ``axis`` as one multiply-reduce — the Pallas
+    kernels' formulation.  Under jit the product fuses into the reduction
+    exactly as in an interpret-mode kernel, so kernels, their oracles and
+    the XLA SELL path agree bit for bit; eager execution, or an unrolled
+    chain of adds, rounds differently on the CPU."""
+    return jnp.sum(vals * g, axis=axis)
+
+
+@partial(jax.jit, static_argnames=("n",))
 def spmv_sell(vals: jax.Array, cols: jax.Array, x: jax.Array,
               n: int) -> jax.Array:
-    """SELL-w SpMV.  vals/cols: (n_slices, max_k, w)."""
+    """SELL-w SpMV.  vals/cols: (n_slices, max_k, w).  Jitted, so the
+    multiply-reduce fuses as it does inside the SELL kernel (bitwise
+    parity)."""
     g = x[cols]                              # (n_slices, max_k, w)
-    y = jnp.einsum("skw,skw->sw", vals, g)   # reduce over k
-    return y.reshape(-1)[:n]
+    return k_sum(vals, g, axis=1).reshape(-1)[:n]
 
 
 def spmv_ell_batched(vals: jax.Array, cols: jax.Array,
@@ -88,15 +98,15 @@ def spmv_ell_batched(vals: jax.Array, cols: jax.Array,
     One gather of the column indices serves all B vectors; the reduction
     over K matches ``spmv_ell`` per column (same order), keeping batched
     and single-RHS PCG arithmetic identical."""
-    return jnp.einsum("rk,rkb->rb", vals, x[cols])
+    return jnp.einsum("kr,krb->rb", vals, x[cols])
 
 
+@partial(jax.jit, static_argnames=("n",))
 def spmv_sell_batched(vals: jax.Array, cols: jax.Array, x: jax.Array,
                       n: int) -> jax.Array:
     """SELL-w SpMV over B column vectors.  x: (n, B) -> (n, B)."""
     g = x[cols]                                    # (n_slices, max_k, w, B)
-    y = jnp.einsum("skw,skwb->swb", vals, g)
-    return y.reshape(-1, x.shape[1])[:n]
+    return k_sum(vals[..., None], g, axis=1).reshape(-1, x.shape[1])[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -113,9 +123,9 @@ def make_sharded_spmv(spmv_format: str, n: int, mesh: Mesh, axis: str,
                       ) -> Callable[[jax.Array], jax.Array]:
     """Distributed SpMV closure over mesh-sharded packed operands.
 
-    ``vals``/``cols`` must be sharded over ``axis`` along their leading
-    (row / slice) dimension, with that dimension a multiple of the axis
-    size; the input vector is replicated and the output is replicated
+    ``vals``/``cols`` must be sharded over ``axis`` along their row
+    dimension (ELL: the minor one of ``(K, n)``; SELL: the leading slice
+    one), with that dimension a multiple of the axis size; the input vector is replicated and the output is replicated
     (each device computes its row block, one tiled all-gather assembles
     the full result).  Per-row arithmetic is identical to the
     single-device ``spmv_ell``/``spmv_sell`` paths, so the distributed
@@ -134,11 +144,11 @@ def make_sharded_spmv(spmv_format: str, n: int, mesh: Mesh, axis: str,
         raise ValueError("spmv_backend='pallas' requires spmv_format='sell' "
                          "(the kernel family is SELL-w)")
     if spmv_format == "ell":
-        row_eq = "rk,rkb->rb" if batched else "rk,rk->r"
+        row_eq = "kr,krb->rb" if batched else "kr,kr->r"
 
-        @partial(shard_map, mesh=mesh,
-                 in_specs=(P(axis, None), P(axis, None), P()),
-                 out_specs=P(), check_rep=False)
+        @partial(jax.shard_map, mesh=mesh,
+                 in_specs=(P(None, axis), P(None, axis), P()),
+                 out_specs=P(), check_vma=False)
         def ell_block(v, c, x):
             y_loc = jnp.einsum(row_eq, v, x[c])
             return jax.lax.all_gather(y_loc, axis, tiled=True)
@@ -146,24 +156,27 @@ def make_sharded_spmv(spmv_format: str, n: int, mesh: Mesh, axis: str,
         return lambda x: ell_block(vals, cols, x)
 
     if spmv_format == "sell":
-        slice_eq = "skw,skwb->swb" if batched else "skw,skw->sw"
         use_kernel = spmv_backend == "pallas"
         if use_kernel:
             # deferred: repro.kernels.__init__ imports repro.core
             from repro.kernels.sell_spmv import sell_spmv_block
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P(axis, None, None), P(axis, None, None), P()),
-                 out_specs=P(), check_rep=False)
+                 out_specs=P(), check_vma=False)
         def sell_block(v, c, x):
             if use_kernel:
                 y_loc = sell_spmv_block(v, c, x, interpret=interpret)
             else:
-                y_loc = jnp.einsum(slice_eq, v, x[c])  # (s, w) or (s, w, B)
+                v_b = v[..., None] if batched else v
+                y_loc = k_sum(v_b, x[c], axis=1)  # (s, w) or (s, w, B)
                 y_loc = y_loc.reshape((-1,) + y_loc.shape[2:])
             return jax.lax.all_gather(y_loc, axis, tiled=True)
 
-        return lambda x: sell_block(vals, cols, x)[:n]
+        # jitted so the multiply-reduce fuses as in the kernel and in
+        # ``spmv_sell`` even when called eagerly (bitwise parity)
+        sell_fn = jax.jit(sell_block)
+        return lambda x: sell_fn(vals, cols, x)[:n]
 
     raise ValueError(f"unknown spmv format {spmv_format!r}")
 

@@ -109,6 +109,23 @@ def curlcurl_like(nx: int, ny: int, nz: int, seed: int = 0) -> sp.csr_matrix:
     return a.tocsr()
 
 
+def thermal2_analogue(g: int) -> sp.csr_matrix:
+    """Thermal2 analogue: g x g 5-point Laplacian with log-normal
+    conductivity jumps (coefficient seed 1).  g = 1108 gives Thermal2's
+    row count (1,227,664 rows)."""
+    rng = np.random.default_rng(1)
+    coeff = np.exp(rng.normal(0, 1, size=(g, g)))
+    return laplace_2d(g, g, coeff)
+
+
+def parabolic_fem_analogue(g: int, dt: float = 0.25) -> sp.csr_matrix:
+    """Parabolic_fem analogue: one implicit step I + dt * L on a g x g
+    grid.  g = 725 gives Parabolic_fem's row count (525,625 rows); a new
+    ``dt`` keeps the sparsity pattern and changes the values."""
+    a = laplace_2d(g, g)
+    return (sp.identity(a.shape[0], format="csr") + dt * a).tocsr()
+
+
 def paper_problem(name: str, scale: str = "small") -> tuple[sp.csr_matrix, str]:
     """Return (A, description).  scale in {tiny, small, bench}."""
     dims = {
@@ -117,16 +134,9 @@ def paper_problem(name: str, scale: str = "small") -> tuple[sp.csr_matrix, str]:
         "bench": dict(g2=352, g3=46, n=120_000, c3=40),
     }[scale]
     if name == "thermal2":
-        ny = nx = dims["g2"]
-        rng = np.random.default_rng(1)
-        coeff = np.exp(rng.normal(0, 1, size=(ny, nx)))
-        return laplace_2d(nx, ny, coeff), "2-D heterogeneous thermal"
+        return thermal2_analogue(dims["g2"]), "2-D heterogeneous thermal"
     if name == "parabolic_fem":
-        nx = ny = dims["g2"]
-        a = laplace_2d(nx, ny)
-        n = a.shape[0]
-        return (sp.identity(n, format="csr") + 0.25 * a).tocsr(), \
-            "implicit parabolic step"
+        return parabolic_fem_analogue(dims["g2"]), "implicit parabolic step"
     if name == "g3_circuit":
         return graph_laplacian(dims["n"]), "irregular circuit-like"
     if name == "audikw_1":

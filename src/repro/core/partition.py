@@ -44,7 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .iccg import pcg_iteration, spmv_ell
 from .plan import BatchedICCGReport, ICCGReport, build_plan
-from .trisolve import DeviceTables, backward_solve, forward_solve
+from .trisolve import DeviceTables, auto_mesh, backward_solve, forward_solve
 
 
 def distributed_iccg(a: sp.spmatrix, b: np.ndarray, mesh: Mesh, *,
@@ -145,9 +145,10 @@ def lower_solver_step(fwd: DeviceTables, bwd: DeviceTables,
     Requires n and R to be multiples of the axis size (arrange via the HBMC
     block/w parameters).
     """
+    mesh = auto_mesh(mesh)
     rep = NamedSharding(mesh, P())
     n = fwd.n_slots - 1
-    assert a_ell_cols.shape[0] == n
+    assert a_ell_cols.shape[1] == n
 
     def one_iteration(x, r, p, rz, vals, cols, fwd_t, bwd_t):
         spmv = lambda v: spmv_ell(vals, cols, v)
@@ -155,7 +156,7 @@ def lower_solver_step(fwd: DeviceTables, bwd: DeviceTables,
         return pcg_iteration(spmv, precond)(x, r, p, rz)
 
     sds = lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
-    row_sh = NamedSharding(mesh, P(axis, None))
+    row_sh = NamedSharding(mesh, P(None, axis))
     sh2 = NamedSharding(mesh, P(None, axis))
     sh3 = NamedSharding(mesh, P(None, axis, None))
     vec = jax.ShapeDtypeStruct((n,), fwd.vals.dtype, sharding=rep)

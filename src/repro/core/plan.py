@@ -49,6 +49,7 @@ from .iccg import (DIVERGENCE_FACTOR, STAGNATION_WINDOW,
 from .trisolve import (BACKENDS, LAYOUTS, DistributedRoundMajorPreconditioner,
                        HBMCPreconditioner, RoundMajorPreconditioner,
                        build_preconditioner_from_rounds,
+                       auto_mesh,
                        build_round_major_preconditioner_from_rounds,
                        shard_fused_tables)
 
@@ -352,6 +353,7 @@ class SolverPlan:
                              "spmv_format='sell' (the kernel family is "
                              "SELL-w)")
         if mesh is not None:
+            mesh = auto_mesh(mesh)
             if layout != "round_major":
                 raise ValueError("mesh= requires layout='round_major' (the "
                                  "sharded apply is the fused round-major "
@@ -368,6 +370,21 @@ class SolverPlan:
             # bitwise identical — the parity oracle of the tests)
             lane_multiple = int(np.lcm(lane_multiple,
                                        mesh.shape[mesh_axis]))
+        if "pallas" in (backend, spmv_backend):
+            # deferred: repro.kernels.__init__ imports repro.core
+            from repro.kernels.config import LANES, SUBLANES, resolve_interpret
+            if not resolve_interpret(interpret):
+                if jnp.dtype(dtype).itemsize > 4:
+                    raise ValueError(
+                        f"compiled Pallas kernels have no "
+                        f"{jnp.dtype(dtype).name} (Mosaic lowers 32-bit "
+                        f"types only); use dtype=jnp.float32, or the 'xla' "
+                        f"backends")
+                if backend == "pallas":
+                    # the compiled sweep tiles each round's lanes in whole
+                    # (8, 128) vreg tiles
+                    lane_multiple = int(np.lcm(lane_multiple,
+                                               SUBLANES * LANES))
         self.method = method
         self.scheduler = scheduler
         self.block_size = block_size
@@ -488,7 +505,7 @@ class SolverPlan:
                     self._spmv_cols = jnp.pad(self._spmv_cols, widths)
                 sh = NamedSharding(mesh, P(ax, None, None))
             else:
-                sh = NamedSharding(mesh, P(ax, None))
+                sh = NamedSharding(mesh, P(None, ax))
             self._spmv_vals = jax.device_put(self._spmv_vals, sh)
             self._spmv_cols = jax.device_put(self._spmv_cols, sh)
         if not self._operands_as_args:

@@ -464,16 +464,20 @@ def pack_sell(a: sp.spmatrix, w: int) -> SellMatrix:
 
 
 def pack_ell(a: sp.spmatrix) -> tuple[np.ndarray, np.ndarray]:
-    """Row-major ELL (the CRS-like gather path for SpMV): (cols, vals)."""
+    """Column-major ELL (the CRS-like gather path for SpMV): (cols, vals),
+    each ``(K, n)`` — slot ``k`` of every row in one plane, rows on the
+    minor axis.  On TPU the minor axis is the 128-wide lane axis: with
+    rows there, the planes and the gather ``x[cols]`` are lane-dense,
+    where an ``(n, K)`` layout pads K (about 5) to 128 lanes."""
     a = sp.csr_matrix(a)
     a.sort_indices()
     n = a.shape[0]
     _check_csr_indices(a, a.shape[1], "pack_ell")
     k = int(np.diff(a.indptr).max(initial=0))
     k = max(k, 1)
-    cols = np.zeros((n, k), dtype=np.int32)
-    vals = np.zeros((n, k), dtype=_pack_dtype(a.data))
+    cols = np.zeros((k, n), dtype=np.int32)
+    vals = np.zeros((k, n), dtype=_pack_dtype(a.data))
     rows_of, k_off = _ell_scatter_indices(a.indptr)
-    cols[rows_of, k_off] = a.indices
-    vals[rows_of, k_off] = a.data
+    cols[k_off, rows_of] = a.indices
+    vals[k_off, rows_of] = a.data
     return cols, vals

@@ -40,8 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .hbmc import HBMCOrdering
 from .sell import (FusedRoundMajorTables, RoundMajorLayout, StepTables,
@@ -259,6 +258,20 @@ def fused_solve_batched(tables: DeviceFusedTables, q: jax.Array) -> jax.Array:
 # blocks -> devices, w lanes -> the vector unit within a device.
 # ---------------------------------------------------------------------------
 
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis in ``Auto`` mode.
+
+    ``jax.make_mesh`` returns ``Explicit`` axes, under which a gather from
+    a sharded operand has no defined output sharding and tracing raises.
+    The solver's sharded paths place their own collectives (``shard_map``)
+    and leave the rest to the compiler's propagation, which is what
+    ``Auto`` axes give."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def _dist_substitute_fused(mesh: Mesh, axis: str, m: int,
                            cols: jax.Array, vals: jax.Array,
                            dinv: jax.Array, q: jax.Array,
@@ -277,8 +290,8 @@ def _dist_substitute_fused(mesh: Mesh, axis: str, m: int,
     t_spec = (P(None, axis, None), P(None, axis, None), P(None, axis))
     q_spec = P(None, axis, None) if batched else P(None, axis)
 
-    @partial(shard_map, mesh=mesh, in_specs=t_spec + (q_spec,),
-             out_specs=P(), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=t_spec + (q_spec,),
+             out_specs=P(), check_vma=False)
     def solve(cols_l, vals_l, dinv_l, q_l):
         s_ = q_l.shape[0]
         r_loc = dinv_l.shape[1]

@@ -2,11 +2,16 @@
 
 hbmc_trisolve — the HBMC forward/backward substitution (the paper's core
 kernel, Fig 4.6 TPU adaptation): round-major layout, sequential grid over
-rounds, VMEM-resident solution vector, VPU gathers, contiguous stores.
+rounds, VMEM-resident solution vector, a staged-row gather Mosaic lowers,
+contiguous stores.
 
 sell_spmv — SELL-w sparse matrix-vector product family (paper §5.2):
-single-RHS, batched multi-RHS, and the shard_map-compatible per-device
-block variant consumed by the mesh-sharded SpMV.
+single-RHS, batched multi-RHS (one product per column), and the
+shard_map-compatible per-device block variant consumed by the
+mesh-sharded SpMV.
+
+Both compile for TPU v5e (f32) and run compiled there
+(tests/test_tpu_compile.py, chip_smoke.py).
 
 Both families ship ref.py pure-jnp oracles (bitwise in interpret mode) and
 the same interpret-by-backend defaulting (config.resolve_interpret), and

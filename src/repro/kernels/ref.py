@@ -1,10 +1,16 @@
-"""Pure-jnp oracles for the Pallas kernels (bit-exact semantics)."""
+"""Pure-jnp oracles for the Pallas kernels (bit-exact semantics).
+
+Jitted, as the kernels are: the CPU compiler then fuses each
+multiply-reduce the same way in both (see ``core.iccg.k_sum``)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.iccg import k_sum
 
+
+@jax.jit
 def hbmc_trisolve_ref(cols: jax.Array, vals: jax.Array, dinv: jax.Array,
                       q: jax.Array) -> jax.Array:
     """Round-major triangular solve, fori_loop + dynamic_update_slice."""
@@ -13,13 +19,14 @@ def hbmc_trisolve_ref(cols: jax.Array, vals: jax.Array, dinv: jax.Array,
 
     def body(s, y):
         g = jnp.take(y, cols[s], axis=0, fill_value=0)     # (R, K)
-        acc = jnp.sum(vals[s] * g, axis=-1)
+        acc = k_sum(vals[s], g, axis=-1)
         t = (q[s] - acc) * dinv[s]
         return jax.lax.dynamic_update_slice(y, t, (s * r_,))
 
     return jax.lax.fori_loop(0, s_, body, y0)
 
 
+@jax.jit
 def hbmc_trisolve_batched_ref(cols: jax.Array, vals: jax.Array,
                               dinv: jax.Array, q: jax.Array) -> jax.Array:
     """Multi-RHS round-major triangular solve.  q: (S, R, B) -> (S*R, B)."""
@@ -29,26 +36,29 @@ def hbmc_trisolve_batched_ref(cols: jax.Array, vals: jax.Array,
 
     def body(s, y):
         g = jnp.take(y, cols[s], axis=0, fill_value=0)     # (R, K, B)
-        acc = jnp.sum(vals[s][..., None] * g, axis=1)      # (R, B)
+        acc = k_sum(vals[s][..., None], g, axis=1)         # (R, B)
         t = (q[s] - acc) * dinv[s][:, None]
         return jax.lax.dynamic_update_slice(y, t, (s * r_, 0))
 
     return jax.lax.fori_loop(0, s_, body, y0)
 
 
+@jax.jit
 def sell_spmv_ref(vals: jax.Array, cols: jax.Array, x: jax.Array) -> jax.Array:
     """SELL-w SpMV oracle.  vals/cols: (n_slices, K, w); x: (n,)."""
     g = jnp.take(x, cols, axis=0, fill_value=0)            # (S, K, w)
-    return jnp.einsum("skw,skw->sw", vals, g).reshape(-1)
+    return k_sum(vals, g, axis=1).reshape(-1)
 
 
+@jax.jit
 def sell_spmv_batched_ref(vals: jax.Array, cols: jax.Array,
                           x: jax.Array) -> jax.Array:
     """Multi-RHS SELL-w SpMV oracle.  x: (n, B) -> (n_slices*w, B)."""
     g = jnp.take(x, cols, axis=0, fill_value=0)            # (S, K, w, B)
-    return jnp.einsum("skw,skwb->swb", vals, g).reshape(-1, x.shape[-1])
+    return k_sum(vals[..., None], g, axis=1).reshape(-1, x.shape[-1])
 
 
+@jax.jit
 def hbmc_trisolve_fused_ref(cols: jax.Array, vals: jax.Array,
                             dinv: jax.Array, q: jax.Array) -> jax.Array:
     """Fused fwd+bwd round-major solve oracle.  cols: (2S, R, K); q: (S, R).
@@ -61,7 +71,8 @@ def hbmc_trisolve_fused_ref(cols: jax.Array, vals: jax.Array,
     oracle reproduces the kernel's exact op order (elementwise multiply +
     jnp.sum -> bit-exact in interpret mode, asserted in tests), while the
     XLA production path contracts with einsum, which is faster on CPU but
-    reassociates the K-reduction.
+    reassociates the K-reduction.  The K-reduction runs in k order
+    (``k_sum``), as the kernel adds one product plane per k.
     """
     s2, r_, k_ = cols.shape
     s_ = s2 // 2
@@ -69,7 +80,7 @@ def hbmc_trisolve_fused_ref(cols: jax.Array, vals: jax.Array,
 
     def body(g, y):
         g_fwd = jnp.take(y, cols[g], axis=0, fill_value=0)     # (R, K)
-        acc = jnp.sum(vals[g] * g_fwd, axis=-1)
+        acc = k_sum(vals[g], g_fwd, axis=-1)
         dest = jnp.where(g < s_, g, s2 - 1 - g) * r_
         q_cur = jnp.where(g < s_, q[jnp.minimum(g, s_ - 1)],
                           jax.lax.dynamic_slice(y, (dest,), (r_,)))
@@ -79,6 +90,7 @@ def hbmc_trisolve_fused_ref(cols: jax.Array, vals: jax.Array,
     return jax.lax.fori_loop(0, s2, body, y0)
 
 
+@jax.jit
 def hbmc_trisolve_fused_batched_ref(cols: jax.Array, vals: jax.Array,
                                     dinv: jax.Array, q: jax.Array
                                     ) -> jax.Array:
@@ -90,7 +102,7 @@ def hbmc_trisolve_fused_batched_ref(cols: jax.Array, vals: jax.Array,
 
     def body(g, y):
         g_fwd = jnp.take(y, cols[g], axis=0, fill_value=0)     # (R, K, B)
-        acc = jnp.sum(vals[g][..., None] * g_fwd, axis=1)      # (R, B)
+        acc = k_sum(vals[g][..., None], g_fwd, axis=1)         # (R, B)
         dest = jnp.where(g < s_, g, s2 - 1 - g) * r_
         zero = jnp.zeros_like(dest)
         q_cur = jnp.where(g < s_, q[jnp.minimum(g, s_ - 1)],

@@ -1,30 +1,35 @@
 """Pallas TPU kernel family for SELL-w sparse matrix-vector products (§5.2).
 
-SELL-C-sigma with C = w: each slice holds w rows column-major so one VPU
-load covers one (k, lane) plane.  The kernels tile slices over the grid;
-x stays VMEM-resident for gathers (same residency argument as the trisolve
-kernel).  Slices are zero-padded to the slice-max row length, matching the
-paper's SELL cost model (the Audikw_1 40%-padding discussion in §5.2.2 is
-reproduced by ``benchmarks/bench_trisolve.py`` via the padded_nnz counter).
+SELL-C-sigma with C = w: each slice holds w rows column-major
+(``(n_slices, K, w)``).  The kernels re-lay the operands out for the TPU
+as ``(K, rows / 128, 128)`` — one lane-dense plane per slot k, matrix rows
+on the 128-wide lane axis — and tile the rows over the grid; x stays
+VMEM-resident for gathers (same residency argument as the trisolve
+kernel), and the gather is the staged-row gather of
+``hbmc_trisolve.gather_rows``.  Slices are zero-padded to the slice-max
+row length, matching the paper's SELL cost model (the Audikw_1
+40%-padding discussion in §5.2.2 is reproduced by
+``benchmarks/bench_trisolve.py`` via the padded_nnz counter).
 
 Three entry points sharing one kernel body:
 
   * ``sell_spmv``          — single RHS, x (n_pad,) -> y (n_slices*w,)
-  * ``sell_spmv_batched``  — B RHS, x (n_pad, B) -> y (n_slices*w, B); the
-    B columns share every gather of the column-index plane, the same
-    amortization as the batched trisolve kernel
+  * ``sell_spmv_batched``  — B RHS, x (n_pad, B) -> y (n_slices*w, B), one
+    single-RHS product per column
   * ``sell_spmv_block``    — shard_map-compatible per-device block variant:
     consumes the LOCAL slice shard of the operands plus the replicated
     vector and returns the local row block (no slicing to n — the caller
     all-gathers; see ``core.iccg.make_sharded_spmv``)
 
 All outputs are in slice-row-major order, padded to ``n_slices * w`` rows;
-callers slice to the matrix dimension (``core.plan._make_spmv`` does).  The
-gather semantics (``jnp.take(..., fill_value=0)``) against zero-padded
-``vals`` make padding lanes contribute exact zeros, so results match the
-jnp oracles in ``ref.py`` bit for bit in interpret mode (asserted in
-tests/test_spmv.py).  ``interpret`` defaults from the backend
-(``config.resolve_interpret``): compiled on TPU, interpreted elsewhere.
+callers slice to the matrix dimension (``core.plan._make_spmv`` does).
+Against zero-padded ``vals`` the gather (indices past x read 0, as
+``jnp.take(..., fill_value=0)`` does) makes padding lanes contribute exact
+zeros, and the K-reduction runs in k order, so results match the jnp
+oracles in ``ref.py`` and the XLA ``spmv_sell`` path bit for bit in
+interpret mode (asserted in tests/test_spmv.py).  ``interpret`` defaults
+from the backend (``config.resolve_interpret``): compiled on TPU,
+interpreted elsewhere.
 """
 from __future__ import annotations
 
@@ -33,37 +38,34 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .config import DEFAULT_SLICE_TILE, resolve_interpret
-
-
-def _sell_spmv_kernel(vals_ref, cols_ref, x_ref, y_ref):
-    vals = vals_ref[...]          # (T, K, w) tile of slices
-    cols = cols_ref[...]          # (T, K, w)
-    x = x_ref[...]                # (n_pad,)
-    g = jnp.take(x, cols, axis=0, fill_value=0)
-    y_ref[...] = jnp.einsum("skw,skw->sw", vals, g)
+from .config import (DEFAULT_SLICE_TILE, LANES, SUBLANES, VMEM_LIMIT_BYTES,
+                     resolve_interpret)
+from .hbmc_trisolve import _Z, gather_rows
 
 
-def _sell_spmv_batched_kernel(vals_ref, cols_ref, x_ref, y_ref):
-    vals = vals_ref[...]          # (T, K, w)
-    cols = cols_ref[...]          # (T, K, w)
-    x = x_ref[...]                # (n_pad, B)
-    g = jnp.take(x, cols, axis=0, fill_value=0)       # (T, K, w, B)
-    y_ref[...] = jnp.einsum("skw,skwb->swb", vals, g)
+def _sell_spmv_kernel(idx_ref, cols_ref, vals_ref, x_ref, y_ref, stage_ref,
+                      *, n_x: int):
+    k_, tile, wc = cols_ref.shape
+
+    def row(i, carry):
+        g_planes = jnp.stack([
+            gather_rows(idx_ref, (k * tile + i) * wc,
+                        cols_ref[k, pl.ds(i, 1), :], x_ref, stage_ref, n_x)
+            for k in range(k_)])                              # (K, 1, wc)
+        y_ref[pl.ds(i, 1), :] = jnp.sum(
+            vals_ref[:, pl.ds(i, 1), :] * g_planes, axis=0)
+        return carry
+
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(tile), row, None)
 
 
-def _pad_slices(vals: jax.Array, cols: jax.Array, slice_tile: int
-                ) -> tuple[jax.Array, jax.Array, int]:
-    """Pad the slice axis to a multiple of the grid tile (zero slices)."""
-    n_slices = vals.shape[0]
-    t = min(slice_tile, n_slices)
-    pad = (-n_slices) % t
-    if pad:
-        widths = ((0, pad),) + ((0, 0),) * (vals.ndim - 1)
-        vals = jnp.pad(vals, widths)
-        cols = jnp.pad(cols, widths)
-    return vals, cols, t
+def _row_tile(slice_tile: int, w: int) -> int:
+    """Rows of LANES matrix rows per grid step: ``slice_tile`` slices,
+    rounded up to whole (8, 128) tiles."""
+    rows = -(-slice_tile * w // LANES)
+    return -(-rows // SUBLANES) * SUBLANES
 
 
 @functools.partial(jax.jit, static_argnames=("slice_tile", "interpret"))
@@ -75,63 +77,66 @@ def sell_spmv(vals: jax.Array, cols: jax.Array, x: jax.Array,
     Args:
       vals: (n_slices, K, w) slice-packed values (0 padding).
       cols: (n_slices, K, w) int32 column indices (padding -> any index whose
-        vals entry is 0; fill_value guards out-of-range).
+        vals entry is 0; indices past x read 0).
       x:    (n_pad,) input vector.
-      slice_tile: slices per grid step (VMEM tile height).
+      slice_tile: slices per grid step (rounded up to whole vreg tiles).
 
     Returns:
       y: (n_slices * w,) in slice-row-major order.
     """
     interpret = resolve_interpret(interpret)
     n_slices, k_, w_ = vals.shape
-    vals, cols, t = _pad_slices(vals, cols, slice_tile)
-    ns = vals.shape[0]
+    n_rows = n_slices * w_
+    tile = _row_tile(slice_tile, w_)
+    n_t = -(-n_rows // (tile * LANES))
+    pad = n_t * tile * LANES - n_rows
+
+    def planes(a):        # (n_slices, K, w) -> (K, rows / LANES, LANES)
+        a = jnp.swapaxes(a, 0, 1).reshape(k_, n_rows)
+        return jnp.pad(a, ((0, 0), (0, pad))).reshape(k_, -1, LANES)
+
+    vals_k, cols_k = planes(vals), planes(cols)
+    idx = (cols_k.reshape(k_, n_t, tile * LANES).transpose(1, 0, 2)
+           .reshape(n_t, 1, -1))
+    n_x = x.shape[0]
+    x2 = jnp.pad(x, (0, -n_x % LANES)).reshape(-1, LANES)
     y = pl.pallas_call(
-        _sell_spmv_kernel,
-        grid=(ns // t,),
+        functools.partial(_sell_spmv_kernel, n_x=n_x),
+        grid=(n_t,),
         in_specs=[
-            pl.BlockSpec((t, k_, w_), lambda i: (i, 0, 0)),
-            pl.BlockSpec((t, k_, w_), lambda i: (i, 0, 0)),
-            pl.BlockSpec((x.shape[0],), lambda i: (0,)),
+            pl.BlockSpec((1, 1, k_ * tile * LANES), lambda t: (t, _Z, _Z),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((k_, tile, LANES), lambda t: (_Z, t, _Z)),
+            pl.BlockSpec((k_, tile, LANES), lambda t: (_Z, t, _Z)),
+            pl.BlockSpec(x2.shape, lambda t: (_Z, _Z)),  # x fully resident
         ],
-        out_specs=pl.BlockSpec((t, w_), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((ns, w_), vals.dtype),
+        out_specs=pl.BlockSpec((tile, LANES), lambda t: (t, _Z)),
+        out_shape=jax.ShapeDtypeStruct((n_t * tile, LANES), vals.dtype),
+        scratch_shapes=[pltpu.VMEM((LANES, LANES), vals.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(vals, cols, x)
-    return y.reshape(-1)[:n_slices * w_]
+    )(idx, cols_k, vals_k, x2)
+    return y.reshape(-1)[:n_rows]
 
 
 @functools.partial(jax.jit, static_argnames=("slice_tile", "interpret"))
 def sell_spmv_batched(vals: jax.Array, cols: jax.Array, x: jax.Array,
                       *, slice_tile: int = DEFAULT_SLICE_TILE,
                       interpret: bool | None = None) -> jax.Array:
-    """Y = A X for B column vectors at once.  x: (n_pad, B).
+    """Y = A X for B column vectors.  x: (n_pad, B).
 
-    One gather of the (K, w) column-index plane serves all B columns; the
-    K-reduction per (row, column) matches ``sell_spmv`` exactly, keeping
-    batched and single-RHS PCG arithmetic identical.
+    One ``sell_spmv`` per column, so each column's K-reduction is exactly
+    the single-RHS one and batched and single-RHS PCG arithmetic stay
+    identical.
 
     Returns:
       y: (n_slices * w, B) in slice-row-major order.
     """
-    interpret = resolve_interpret(interpret)
-    n_slices, k_, w_ = vals.shape
-    b_ = x.shape[-1]
-    vals, cols, t = _pad_slices(vals, cols, slice_tile)
-    ns = vals.shape[0]
-    y = pl.pallas_call(
-        _sell_spmv_batched_kernel,
-        grid=(ns // t,),
-        in_specs=[
-            pl.BlockSpec((t, k_, w_), lambda i: (i, 0, 0)),
-            pl.BlockSpec((t, k_, w_), lambda i: (i, 0, 0)),
-            pl.BlockSpec((x.shape[0], b_), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((t, w_, b_), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((ns, w_, b_), vals.dtype),
-        interpret=interpret,
-    )(vals, cols, x)
-    return y.reshape(-1, b_)[:n_slices * w_]
+    return jnp.stack([sell_spmv(vals, cols, x[:, b], slice_tile=slice_tile,
+                                interpret=interpret)
+                      for b in range(x.shape[-1])], axis=-1)
 
 
 def sell_spmv_block(vals: jax.Array, cols: jax.Array, x: jax.Array,

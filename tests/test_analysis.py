@@ -301,6 +301,19 @@ def test_kernel_checks_catch_corruption_and_vmem():
     assert any(v.kind == "grid-divisibility" for v in vio)
 
 
+def test_kernel_vmem_budget_is_the_kernels_limit():
+    """The static budget is the scoped VMEM the kernels request, and the
+    Thermal2 analogue at its row count (the compiled plan's padded
+    shapes) fits it."""
+    from repro.analysis import (VMEM_BUDGET_BYTES, sell_spmv_vmem_bytes,
+                                trisolve_fused_vmem_bytes)
+    from repro.kernels.config import VMEM_LIMIT_BYTES
+    assert VMEM_BUDGET_BYTES == VMEM_LIMIT_BYTES
+    assert trisolve_fused_vmem_bytes(256, 19456, 4, 4) <= VMEM_BUDGET_BYTES
+    assert (sell_spmv_vmem_bytes(256, 5, 8, 128 * 19456, 4)
+            <= VMEM_BUDGET_BYTES)
+
+
 def test_sell_kernel_checks():
     a = laplace_2d(10, 8)
     sm = pack_sell(a, 4)

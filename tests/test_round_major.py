@@ -16,7 +16,7 @@ import pytest
 import scipy.sparse as sp
 
 from repro.analysis import ROUND_MAJOR_APPLY, lint, primitives
-from repro.core import (build_preconditioner_from_rounds,
+from repro.core import (build_plan, build_preconditioner_from_rounds,
                         build_round_major_preconditioner_from_rounds,
                         fuse_round_major, ic0, pack_ell, pack_factor,
                         permute_round_major, solve_iccg,
@@ -237,3 +237,21 @@ def test_fuse_rejects_mismatched_rounds():
 
 def test_default_interpret_tracks_backend():
     assert default_interpret() == (jax.default_backend() != "tpu")
+
+
+def test_compiled_pallas_plan_pads_rounds_and_refuses_f64():
+    """A plan whose kernels will be compiled pads each round to whole
+    (8, 128) tiles and refuses f64, which Mosaic cannot lower; plans with
+    interpreted kernels keep the packed width."""
+    a = laplace_2d(14, 12)
+    knobs = dict(method="hbmc", block_size=8, w=4)
+    compiled = build_plan(a, backend="pallas", interpret=False,
+                          dtype=jnp.float32, **knobs)
+    interpreted = build_plan(a, backend="pallas", interpret=True, **knobs)
+    assert compiled._precond.tables.lanes % 1024 == 0
+    assert interpreted._precond.tables.lanes < 1024
+    with pytest.raises(ValueError, match="float64"):
+        build_plan(a, backend="pallas", interpret=False, **knobs)
+    with pytest.raises(ValueError, match="float64"):
+        build_plan(a, spmv_format="sell", spmv_backend="pallas",
+                   interpret=False, **knobs)

@@ -68,7 +68,7 @@ def test_ic0_rounds_unchanged_pcg_iterations(method):
     a_rm = sell.permute_round_major(sysd.a_bar, rm)
     cols, vals = sell.pack_ell(a_rm)
     vals_d, cols_d = jnp.asarray(vals), jnp.asarray(cols)
-    res = pcg(lambda x: jnp.einsum("rk,rk->r", vals_d, x[cols_d]), pre,
+    res = pcg(lambda x: jnp.einsum("kr,kr->r", vals_d, x[cols_d]), pre,
               jnp.asarray(rm.embed(sysd.b_bar)))
     assert rep.result.iterations == res.iterations
     assert rep.result.converged
@@ -148,15 +148,15 @@ def test_pack_ell_and_sell_match_reference():
     a = sp.csr_matrix(graph_laplacian(200, avg_degree=5, seed=3))
     a.sort_indices()
     cols, vals = sell.pack_ell(a)
-    n, k = a.shape[0], cols.shape[1]
+    n, k = a.shape[0], cols.shape[0]
     cols_ref = np.zeros((n, k), dtype=np.int32)
     vals_ref = np.zeros((n, k))
     for r in range(n):
         lo, hi = a.indptr[r], a.indptr[r + 1]
         cols_ref[r, :hi - lo] = a.indices[lo:hi]
         vals_ref[r, :hi - lo] = a.data[lo:hi]
-    np.testing.assert_array_equal(cols, cols_ref)
-    np.testing.assert_array_equal(vals, vals_ref)
+    np.testing.assert_array_equal(cols, cols_ref.T)
+    np.testing.assert_array_equal(vals, vals_ref.T)
 
     w = 4
     sm = sell.pack_sell(a, w)
