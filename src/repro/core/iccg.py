@@ -12,7 +12,7 @@ Convergence criterion: relative residual 2-norm < rtol (paper: 1e-7).
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 from typing import Callable, NamedTuple
 
 import jax
@@ -60,6 +60,42 @@ DIVERGENCE_FACTOR = 1e8
 #: default stagnation window: iterations without a new best relres before
 #: STAGNATED trips.  Healthy ICCG improves its best every few iterations.
 STAGNATION_WINDOW = 1000
+
+
+# ---------------------------------------------------------------------------
+# Program scopes of the PCG's device work.
+#
+# ``jax.named_scope`` puts these names into the ``op_name`` metadata of every
+# HLO instruction the traced code emits, which names the ops of a device
+# trace: the SpMV, the preconditioner apply (the fused sweep) and the
+# vector work (dots, axpys, the norm, the health-guard selects) of the PCG
+# loop.  Scopes change metadata only, never an instruction or an answer.
+# The preconditioner and SpMV scopes nest inside the vector scope, so an
+# op's scope is the innermost of these names in its ``op_name``.
+# ---------------------------------------------------------------------------
+
+SPMV_SCOPE = "pcg.spmv"
+SWEEP_SCOPE = "pcg.sweep"
+VECTOR_SCOPE = "pcg.vector"
+PCG_SCOPES = (SPMV_SCOPE, SWEEP_SCOPE, VECTOR_SCOPE)
+
+
+def _in_scope(name: str, fn: Callable) -> Callable:
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return scoped
+
+
+def _scoped_pcg(core: Callable) -> Callable:
+    """Run a PCG core under ``VECTOR_SCOPE`` with its ``spmv`` under
+    ``SPMV_SCOPE`` and its ``precond`` under ``SWEEP_SCOPE``."""
+    @wraps(core)
+    def run(spmv, precond, *args, **kwargs):
+        with jax.named_scope(VECTOR_SCOPE):
+            return core(_in_scope(SPMV_SCOPE, spmv),
+                        _in_scope(SWEEP_SCOPE, precond), *args, **kwargs)
+    return run
 
 
 def status_name(code) -> str:
@@ -220,6 +256,7 @@ class PCGResult:
     status: str = "CONVERGED"
 
 
+@_scoped_pcg
 def _pcg_device(spmv: Callable[[jax.Array], jax.Array],
                 precond: Callable[[jax.Array], jax.Array],
                 b: jax.Array,
@@ -381,6 +418,7 @@ class BatchedPCGResult:
         return [STATUS_NAMES[int(s)] for s in self.status]
 
 
+@_scoped_pcg
 def _pcg_batched_device(spmv: Callable[[jax.Array], jax.Array],
                         precond: Callable[[jax.Array], jax.Array],
                         b: jax.Array,
@@ -607,6 +645,7 @@ class SlabState(NamedTuple):
     since_best: jax.Array  # (B,) iterations since best improved (int32)
 
 
+@_scoped_pcg
 def _pcg_slab_device(spmv: Callable[[jax.Array], jax.Array],
                      precond: Callable[[jax.Array], jax.Array],
                      state: SlabState,

@@ -46,6 +46,7 @@ from .iccg import (DIVERGENCE_FACTOR, STAGNATION_WINDOW,
                    _pcg_batched_device, _pcg_device, _pcg_slab_device,
                    make_sharded_spmv, spmv_ell, spmv_ell_batched, spmv_sell,
                    spmv_sell_batched, status_name)
+from .timing import SOLVE_EMBED, SOLVE_EXTRACT, SOLVE_PCG, span
 from .trisolve import (BACKENDS, LAYOUTS, DistributedRoundMajorPreconditioner,
                        HBMCPreconditioner, RoundMajorPreconditioner,
                        build_preconditioner_from_rounds,
@@ -70,6 +71,10 @@ class ICCGReport:
     layout: str = "round_major"
     spmv_backend: str = "xla"
     scheduler: str = "coloring"
+    # host path of the solve (``setup_seconds`` is the embed interval
+    # unless a wrapper adds its plan build to it)
+    embed_seconds: float = 0.0
+    extract_seconds: float = 0.0
 
 
 @dataclasses.dataclass
@@ -88,6 +93,8 @@ class BatchedICCGReport:
     layout: str = "round_major"
     spmv_backend: str = "xla"
     scheduler: str = "coloring"
+    embed_seconds: float = 0.0
+    extract_seconds: float = 0.0
 
 
 @dataclasses.dataclass
@@ -911,35 +918,38 @@ class SolverPlan:
         agree with ``plan.solve`` to reduction-order rounding only (XLA
         lowers the batched ``einsum`` dots differently from ``vdot``).
         """
-        t0 = time.perf_counter()
-        b = np.asarray(b, dtype=self._np_dtype)
-        if b.shape != (self.n,):
-            raise ValueError(f"plan.solve_slab expects b of shape "
-                             f"({self.n},), got {b.shape}")
-        if not 0 <= slot < slab_width:
-            raise ValueError(f"slot {slot} out of range for slab_width "
-                             f"{slab_width}")
-        state = self.new_slab_state(slab_width)
-        state = state._replace(
-            r=state.r.at[:, slot].set(self.embed_rhs(b)))
-        t1 = time.perf_counter()
-        state, _ = self.run_slab(state, rtol=rtol, maxiter=maxiter,
-                                 quantum=maxiter)
-        x = jax.block_until_ready(state.x)
-        t2 = time.perf_counter()
-        x_out = self.extract_solution(x[:, slot])
-        relres = float(state.relres[slot])
-        res = PCGResult(x=x_out, iterations=int(state.iters[slot]),
-                        relres=relres, converged=relres < rtol,
-                        history=np.zeros((0,)),
-                        status=status_name(state.status[slot]))
+        t = {}
+        with span(SOLVE_EMBED, t):
+            b = np.asarray(b, dtype=self._np_dtype)
+            if b.shape != (self.n,):
+                raise ValueError(f"plan.solve_slab expects b of shape "
+                                 f"({self.n},), got {b.shape}")
+            if not 0 <= slot < slab_width:
+                raise ValueError(f"slot {slot} out of range for "
+                                 f"slab_width {slab_width}")
+            state = self.new_slab_state(slab_width)
+            state = state._replace(
+                r=state.r.at[:, slot].set(self.embed_rhs(b)))
+        with span(SOLVE_PCG, t):
+            state, _ = self.run_slab(state, rtol=rtol, maxiter=maxiter,
+                                     quantum=maxiter)
+            x = jax.block_until_ready(state.x)
+        with span(SOLVE_EXTRACT, t):
+            x_out = self.extract_solution(x[:, slot])
+            relres = float(state.relres[slot])
+            iters = int(state.iters[slot])
+            status = status_name(state.status[slot])
+        res = PCGResult(x=x_out, iterations=iters, relres=relres,
+                        converged=relres < rtol, history=np.zeros((0,)),
+                        status=status)
         return ICCGReport(
             method=self.method, result=res, n=self.n,
             n_padded=self.n_padded, n_colors=self.n_colors,
-            n_rounds=self.n_rounds, setup_seconds=t1 - t0,
-            solve_seconds=t2 - t1, lane_occupancy=self.lane_occupancy,
+            n_rounds=self.n_rounds, setup_seconds=t[SOLVE_EMBED],
+            solve_seconds=t[SOLVE_PCG], lane_occupancy=self.lane_occupancy,
             x=x_out, backend=self.backend, layout=self.layout,
-            spmv_backend=self.spmv_backend, scheduler=self.scheduler)
+            spmv_backend=self.spmv_backend, scheduler=self.scheduler,
+            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT])
 
     def solve(self, b: np.ndarray, rtol: float = 1e-7,
               maxiter: int = 10_000,
@@ -949,60 +959,67 @@ class SolverPlan:
         Per-call host work is exactly: embed ``b`` into the solve layout,
         extract ``x`` back into the caller's ordering.
         """
-        t0 = time.perf_counter()
-        b = np.asarray(b, dtype=self._np_dtype)
-        if b.shape != (self.n,):
-            raise ValueError(f"plan.solve expects b of shape ({self.n},), "
-                             f"got {b.shape}")
-        b_bar = np.zeros(self.n_padded, dtype=self._np_dtype)
-        b_bar[self._sysd.perm] = b
-        b_dev = self._embed(b_bar)
-        t1 = time.perf_counter()
-        x, it, relres, status, hist = self._run_pcg(False, rtol, maxiter,
-                                                    record_history, b_dev)
-        x = jax.block_until_ready(x)
-        t2 = time.perf_counter()
-        x_out = self._extract(x)
-        relres = float(relres)
-        res = PCGResult(x=x_out, iterations=int(it), relres=relres,
-                        converged=relres < rtol, history=np.asarray(hist),
-                        status=status_name(status))
+        t = {}
+        with span(SOLVE_EMBED, t):
+            b = np.asarray(b, dtype=self._np_dtype)
+            if b.shape != (self.n,):
+                raise ValueError(f"plan.solve expects b of shape "
+                                 f"({self.n},), got {b.shape}")
+            b_bar = np.zeros(self.n_padded, dtype=self._np_dtype)
+            b_bar[self._sysd.perm] = b
+            b_dev = self._embed(b_bar)
+        with span(SOLVE_PCG, t):
+            x, it, relres, status, hist = self._run_pcg(
+                False, rtol, maxiter, record_history, b_dev)
+            x = jax.block_until_ready(x)
+        with span(SOLVE_EXTRACT, t):
+            x_out = self._extract(x)
+            relres, it = float(relres), int(it)
+            status, hist = status_name(status), np.asarray(hist)
+        res = PCGResult(x=x_out, iterations=it, relres=relres,
+                        converged=relres < rtol, history=hist,
+                        status=status)
         return ICCGReport(
             method=self.method, result=res, n=self.n,
             n_padded=self.n_padded, n_colors=self.n_colors,
-            n_rounds=self.n_rounds, setup_seconds=t1 - t0,
-            solve_seconds=t2 - t1, lane_occupancy=self.lane_occupancy,
+            n_rounds=self.n_rounds, setup_seconds=t[SOLVE_EMBED],
+            solve_seconds=t[SOLVE_PCG], lane_occupancy=self.lane_occupancy,
             x=x_out, backend=self.backend, layout=self.layout,
-            spmv_backend=self.spmv_backend, scheduler=self.scheduler)
+            spmv_backend=self.spmv_backend, scheduler=self.scheduler,
+            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT])
 
     def solve_batched(self, b: np.ndarray, rtol: float = 1e-7,
                       maxiter: int = 10_000,
                       record_history: bool = False) -> BatchedICCGReport:
         """Solve A x_j = b_j for all columns of ``b`` ((n, B)) in one PCG
         loop, reusing every cached setup product."""
-        t0 = time.perf_counter()
-        b = self._check_slab(b, "plan.solve_batched")
-        b_bar = np.zeros((self.n_padded, b.shape[1]), dtype=self._np_dtype)
-        b_bar[self._sysd.perm] = b
-        b_dev = self._embed(b_bar)
-        t1 = time.perf_counter()
-        x, iters, relres, step, status, hist = self._run_pcg(
-            True, rtol, maxiter, record_history, b_dev)
-        x = jax.block_until_ready(x)
-        t2 = time.perf_counter()
-        x_out = self._extract(x)
-        relres = np.asarray(relres)
-        res = BatchedPCGResult(x=x_out, iterations=np.asarray(iters),
-                               relres=relres, converged=relres < rtol,
-                               n_steps=int(step), history=np.asarray(hist),
-                               status=np.asarray(status))
+        t = {}
+        with span(SOLVE_EMBED, t):
+            b = self._check_slab(b, "plan.solve_batched")
+            b_bar = np.zeros((self.n_padded, b.shape[1]),
+                             dtype=self._np_dtype)
+            b_bar[self._sysd.perm] = b
+            b_dev = self._embed(b_bar)
+        with span(SOLVE_PCG, t):
+            x, iters, relres, step, status, hist = self._run_pcg(
+                True, rtol, maxiter, record_history, b_dev)
+            x = jax.block_until_ready(x)
+        with span(SOLVE_EXTRACT, t):
+            x_out = self._extract(x)
+            relres, iters, step = (np.asarray(relres), np.asarray(iters),
+                                   int(step))
+            status, hist = np.asarray(status), np.asarray(hist)
+        res = BatchedPCGResult(x=x_out, iterations=iters, relres=relres,
+                               converged=relres < rtol, n_steps=step,
+                               history=hist, status=status)
         return BatchedICCGReport(
             method=self.method, result=res, n=self.n,
             n_padded=self.n_padded, n_colors=self.n_colors,
-            n_rounds=self.n_rounds, setup_seconds=t1 - t0,
-            solve_seconds=t2 - t1, lane_occupancy=self.lane_occupancy,
+            n_rounds=self.n_rounds, setup_seconds=t[SOLVE_EMBED],
+            solve_seconds=t[SOLVE_PCG], lane_occupancy=self.lane_occupancy,
             x=x_out, backend=self.backend, layout=self.layout,
-            spmv_backend=self.spmv_backend, scheduler=self.scheduler)
+            spmv_backend=self.spmv_backend, scheduler=self.scheduler,
+            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT])
 
 
 def build_plan(a: sp.spmatrix, method: str = "hbmc", block_size: int = 32,
