@@ -84,7 +84,7 @@ def _rows(args, devices) -> list[dict]:
                                                 block_size=bs, w=w)
     for (method, meshed), plan in sorted(plans.items()):
         tab = plan._precond.tables
-        dim = tab.n_steps * tab.lanes
+        dim = tab.m
         for batch in BATCHES:
             r = jnp.asarray(rng.normal(
                 size=(dim,) if batch == 1 else (dim, batch)))
@@ -113,7 +113,8 @@ def _rows(args, devices) -> list[dict]:
             rows.append({
                 "n_devices": n_dev, "mesh": meshed, "method": method,
                 "B": batch, "n": int(n),
-                "rounds": int(tab.n_steps), "lanes": int(tab.lanes),
+                "rounds": int(tab.n_steps),
+                "lanes": [int(r) for _, r in tab.segments],
                 "apply_us": round(apply_us, 1),
                 "solve_us": round(rep.solve_seconds * 1e6, 1),
                 "iterations": its,

@@ -33,7 +33,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import scipy.sparse as sp  # noqa: E402
 
-from repro.core import (LAYOUTS, RoundMajorPreconditioner,  # noqa: E402
+from repro.core import (LAYOUTS,  # noqa: E402
                         build_round_major_preconditioner_from_rounds, sell,
                         solve_iccg, solve_iccg_batched)
 from repro.core.ic0 import ic0_refactor, ic0_structure  # noqa: E402
@@ -104,14 +104,15 @@ def bench_iteration_breakdown(name, a, *, reps):
     """
     rng = np.random.default_rng(7)
     sysd = _order_system(sp.csr_matrix(a), None, "hbmc", BS, W)
-    # factor + pack once; the two trisolve backends share the device tables
+    # factor once; the Pallas sweep packs its rounds at one width, the XLA
+    # sweep per segment, so each backend prices its own layout
     st = ic0_structure(sysd.a_bar, sysd.fwd_rounds)
     l_bar = ic0_refactor(st, sysd.a_bar)
-    pre_xla, rm = build_round_major_preconditioner_from_rounds(
-        l_bar, sysd.fwd_rounds, sysd.bwd_rounds, drop_mask=sysd.drop)
-    precs = {"xla": pre_xla,
-             "pallas": RoundMajorPreconditioner(tables=pre_xla.tables,
-                                                backend="pallas")}
+    precs = {tb: build_round_major_preconditioner_from_rounds(
+                 l_bar, sysd.fwd_rounds, sysd.bwd_rounds,
+                 drop_mask=sysd.drop, backend=tb)
+             for tb in SPMV_BACKENDS}
+    rm = precs["xla"][1]
     a_rm = sell.permute_round_major(sysd.a_bar, rm)
     sm = sell.pack_sell(a_rm, W)
     vals, cols = jnp.asarray(sm.vals), jnp.asarray(sm.cols)
@@ -131,8 +132,11 @@ def bench_iteration_breakdown(name, a, *, reps):
                                       batched=batch != 1, spmv_backend=sb))
             row("spmv", sb, batch, _time_apply(spmv, r, reps))
         for tb in SPMV_BACKENDS:
-            apply_fn = precs[tb] if batch == 1 else precs[tb].apply_batched
-            row("precond", tb, batch, _time_apply(apply_fn, r, reps))
+            pre, lay = precs[tb]
+            apply_fn = pre if batch == 1 else pre.apply_batched
+            r_tb = r if lay.m == m else jnp.asarray(
+                rng.normal(size=(lay.m,) + shape[1:]))
+            row("precond", tb, batch, _time_apply(apply_fn, r_tb, reps))
         vw = _vec_work_single if batch == 1 else _vec_work_batched
         rz = jnp.asarray(1.0) if batch == 1 else jnp.ones(batch)
         row("vector", "xla", batch, _time_call(vw, (r, r, r, r, r, rz),

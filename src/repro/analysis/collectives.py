@@ -7,10 +7,11 @@ proves that at the jaxpr level (one ``all_gather`` eqn in the traced loop
 body); this module proves it survives XLA: the *optimized* HLO of a mesh
 plan must contain
 
-  * exactly one all-gather inside exactly one while body for the fused
-    apply, with the while's ``known_trip_count`` equal to 2S (S = color
-    rounds; the fused sweep runs forward + backward halves), and the
-    gather tiled (result bytes == participants x operand bytes);
+  * exactly one all-gather inside each of the apply's while bodies, one
+    forward and one backward loop per lane-width segment, each loop's
+    ``known_trip_count`` equal to its segment's rounds (2S steps in all,
+    S = color rounds), and every gather tiled (result bytes ==
+    participants x operand bytes);
   * exactly one collective (an all-gather) in the sharded SpMV;
   * zero ``all-reduce`` / ``reduce-scatter`` / ``all-to-all`` /
     ``collective-permute`` anywhere in the whole PCG solve — the state
@@ -26,6 +27,7 @@ Built on the shared HLO parse in ``analysis.hlo``; witnesses reuse
 from __future__ import annotations
 
 import dataclasses
+from typing import Sequence
 
 from . import hlo
 from .schedule import ScheduleError, Violation
@@ -103,17 +105,20 @@ def _check_tiled(text: str, where: str) -> list[Violation]:
     return out
 
 
-def check_collective_structure(text: str, *, n_rounds: int | None = None,
+def check_collective_structure(text: str, *,
+                               n_rounds: int | Sequence[int] | None = None,
                                expect_gathers: int | None = None,
                                where: str = "collectives"
                                ) -> list[Violation]:
     """Structural proof over one optimized module.
 
     Always enforced: no forbidden collective kinds, at most one all-gather
-    per while body, every gather tiled.  ``n_rounds`` additionally pins
-    the sweep shape: exactly one collective-bearing while body whose trip
-    count is ``2 * n_rounds``.  ``expect_gathers`` pins the module-wide
-    static all-gather op count (e.g. 1 for the sharded SpMV).
+    per while body, every gather tiled.  ``n_rounds`` (the rounds of each
+    lane-width segment; an int is one segment) additionally pins the sweep
+    shape: one collective-bearing while body per segment and half, whose
+    trip counts are the segments' rounds, each twice.  ``expect_gathers``
+    pins the module-wide static all-gather op count (e.g. 1 for the
+    sharded SpMV).
     """
     bodies, counts = collective_bodies(text)
     out: list[Violation] = []
@@ -136,26 +141,30 @@ def check_collective_structure(text: str, *, n_rounds: int | None = None,
                        f"all-gathers per step ({', '.join(b.gathers)}); "
                        f"the sweep contract is one"))
     if n_rounds is not None:
-        want_trip = 2 * n_rounds
+        rounds = [n_rounds] if isinstance(n_rounds, int) else list(n_rounds)
+        want = sorted(2 * rounds)
         sweep = [b for b in bodies if b.gathers]
+        trips = sorted(b.trip for b in sweep)
         if not sweep:
             out.append(Violation(
                 kind="missing-collective", where=where,
-                detail="no while body contains an all-gather — the fused "
-                       "sweep lost its per-round tile exchange"))
-        elif len(sweep) > 1:
+                detail="no while body contains an all-gather — the sweep "
+                       "lost its per-round tile exchange"))
+        elif len(sweep) != len(want):
             out.append(Violation(
-                kind="extra-collective", where=where,
+                kind="extra-collective" if len(sweep) > len(want)
+                else "missing-collective", where=where,
                 detail=f"{len(sweep)} collective-bearing while bodies "
-                       f"({', '.join(b.comp for b in sweep)}); the fused "
-                       f"apply has exactly one sweep loop"))
-        elif sweep[0].trip != want_trip:
+                       f"({', '.join(b.comp for b in sweep)}); the apply "
+                       f"has {len(want)} sweep loops (a forward and a "
+                       f"backward loop per segment)"))
+        elif trips != want:
+            bad = next(t for t, w in zip(trips, want) if t != w)
             out.append(Violation(
-                kind="trip-count-mismatch", where=where,
-                round=sweep[0].trip,
-                detail=f"sweep body {sweep[0].comp} runs "
-                       f"{sweep[0].trip} steps, expected 2S = "
-                       f"{want_trip} (S = {n_rounds} rounds)"))
+                kind="trip-count-mismatch", where=where, round=bad,
+                detail=f"sweep bodies run {trips} steps, expected {want}: "
+                       f"2S = {2 * sum(rounds)} (S = {sum(rounds)} rounds "
+                       f"in {len(rounds)} segment(s))"))
     if expect_gathers is not None:
         got = counts.get("all-gather", 0)
         if got != expect_gathers:
@@ -208,12 +217,13 @@ def check_plan_collectives(plan) -> list[Violation]:
                              spmv_backend=plan.spmv_backend,
                              interpret=plan.interpret)
     out += check_collective_structure(
-        optimized_hlo(lambda x: pre(x), q), n_rounds=plan.n_rounds,
+        optimized_hlo(lambda x: pre(x), q),
+        n_rounds=[n for n, _ in pre.tables.segments],
         where="collectives/apply")
     out += check_collective_structure(
         optimized_hlo(spmv, q), expect_gathers=1, where="collectives/spmv")
-    # whole solve: the two sweep loops (init + iteration) and the SpMV may
-    # each gather; nothing may reduce — replicated state needs no
+    # whole solve: the sweep loops (init and iteration applies) and the
+    # SpMV may each gather; nothing may reduce — replicated state needs no
     # all-reduce for the dot pairings
     fn = plan._pcg_fn(False, 1e-8, 8, False)
     solve_text = fn.lower(plan._precond.tables, plan._spmv_vals,

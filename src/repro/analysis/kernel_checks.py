@@ -193,8 +193,7 @@ def check_plan_kernels(plan, vmem_budget: int = VMEM_BUDGET_BYTES
     """
     out: list[Violation] = []
     if plan.backend == "pallas" and plan.layout == "round_major":
-        t = plan._precond.tables
-        out += check_trisolve_fused(t.cols, t.vals, t.dinv,
+        out += check_trisolve_fused(*plan._precond.tables.stacked(),
                                     vmem_budget=vmem_budget)
     if plan.spmv_backend == "pallas":
         out += check_sell_spmv(plan._spmv_vals, plan._spmv_cols,

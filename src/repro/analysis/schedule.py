@@ -19,9 +19,10 @@ The checkers here verify that property at three levels of materialization:
                            pattern (the O(nnz) "cheap" proof)
   ``check_step_tables``    the packed per-round gather tables
                            (``sell.StepTables`` — what the XLA sweep runs)
-  ``check_fused_tables``   the fused fwd+bwd round-major tables
+  ``check_fused_tables``   the segmented fwd+bwd round-major tables
                            (``sell.FusedRoundMajorTables`` — what the
-                           Pallas kernel and the shard_map sweep run)
+                           round-major sweeps, sharded or not, and the
+                           Pallas kernel run)
   ``check_ic0_structure``  the IC(0) factorization step schedule
                            (``ic0.IC0Structure`` — the setup pipeline)
 
@@ -307,63 +308,76 @@ def check_step_tables(tables, tri: sp.spmatrix | None = None,
 
 def check_fused_tables(fused, where: str = "fused_tables"
                        ) -> list[Violation]:
-    """Verify fused fwd+bwd round-major tables
-    (``sell.FusedRoundMajorTables`` or ``trisolve.DeviceFusedTables`` +
-    layout) are triangular in execution order.
+    """Verify round-major tables (``sell.FusedRoundMajorTables`` or
+    ``trisolve.DeviceFusedTables``) are triangular in execution order.
 
-    In forward round-major coordinates, step ``g`` of the fused 2S-step
-    schedule writes the contiguous destination slice ``d(g)*R`` with
-    ``d(g) = g`` (forward half) or ``2S-1-g`` (backward half).  The race
-    freedom proof is positional: every live gather of the forward half must
-    read strictly BELOW its destination slice (already-written ``y``), every
-    live gather of the backward half strictly ABOVE it (already-overwritten
-    ``z`` — its dependencies), and pad gathers (``cols == m``) must carry
-    zero values so the ``fill_value=0`` read is inert.
+    In flat round-major coordinates segment ``c``'s forward round ``j``
+    writes the contiguous slice from ``o_c + j R_c``, and its backward
+    step ``j`` the slice of forward round ``n_c - 1 - j``; the forward
+    sweep runs the segments in order (steps ``0..S-1``), the backward
+    sweep in reverse order (steps ``S..2S-1``).  The race freedom proof is
+    positional: every live gather of a forward round must read strictly
+    BELOW its destination (already-written ``y``), every live gather of a
+    backward round strictly ABOVE it (already-overwritten ``z`` — its
+    dependencies), and pad gathers (``cols == m``) must carry zero values
+    so the ``fill_value=0`` read is inert.
     """
-    cols = np.asarray(fused.cols)
-    vals = np.asarray(fused.vals)
+    segs = [tuple(np.asarray(x) for x in (f.cols, f.vals, b.cols, b.vals))
+            for f, b in zip(fused.fwd, fused.bwd)]
+    sizes = [tuple(f.dinv.shape) for f in fused.fwd]
     lay = getattr(fused, "layout", None)
-    s2, r_, k_ = cols.shape
-    s_ = s2 // 2
-    m = s_ * r_
     out: list[Violation] = []
-    if s2 != 2 * s_ or (lay is not None and lay.n_steps != s_):
+    shapes = [(c.shape[0], c.shape[2]) for s in segs for c in (s[0], s[2])]
+    if (len(fused.fwd) != len(fused.bwd)
+            or shapes != [s for s in sizes for _ in range(2)]
+            or (lay is not None and tuple(lay.segments) != tuple(sizes))):
         out.append(Violation(
             kind="shape-mismatch", where=where,
-            detail=f"fused tables have {s2} steps, expected 2*S"))
+            detail=f"forward segments {sizes} disagree with the backward "
+                   f"tables or the layout"))
         return out
+    m = sum(n * r for n, r in sizes)
+    offsets = np.cumsum([0] + [n * r for n, r in sizes[:-1]])
+    dests = [off + np.arange(n * r).reshape(n, r)
+             for off, (n, r) in zip(offsets, sizes)]
+    # (first global step, cols, vals, destination per lane, half)
+    halves, g0 = [], 0
+    for (fc, fv, _, _), dest in zip(segs, dests):
+        halves.append((g0, fc, fv, dest, "forward"))
+        g0 += len(fc)
+    for (_, _, bc, bv), dest in reversed(list(zip(segs, dests))):
+        halves.append((g0, bc, bv, dest[::-1], "backward"))
+        g0 += len(bc)
 
-    oob = (cols < 0) | (cols > m)
-    if oob.any():
-        g, t, k = (int(x) for x in np.argwhere(oob)[0])
-        out.append(Violation(
-            kind="index-out-of-range", where=where, round=g,
-            detail=f"cols[{g},{t},{k}] = {int(cols[g, t, k])} outside "
-                   f"[0, {m}]"))
-    pad_val = (cols == m) & (vals != 0)
-    if pad_val.any():
-        g, t, k = (int(x) for x in np.argwhere(pad_val)[0])
-        out.append(Violation(
-            kind="nonzero-pad-value", where=where, round=g,
-            detail=f"vals[{g},{t},{k}] = {vals[g, t, k]!r} on the "
-                   f"out-of-range pad position"))
-
-    pos = np.arange(m).reshape(s_, r_)
-    dest = np.concatenate([pos, pos[::-1]])[:, :, None]
-    live = (vals != 0) & (cols < m)
-    fwd_bad = live[:s_] & (cols[:s_] >= dest[:s_])
-    bwd_bad = live[s_:] & (cols[s_:] <= dest[s_:])
-    for half, bad, goff, word in (("forward", fwd_bad, 0, "below"),
-                                  ("backward", bwd_bad, s_, "above")):
-        for g, t, k in np.argwhere(bad)[:MAX_VIOLATIONS - len(out)]:
-            g, t, k = int(g), int(t), int(k)
-            src = int(cols[goff + g, t, k])
-            dst = int(dest[goff + g, t, 0])
+    for g0, cols, vals, dest, half in halves:
+        # cols / vals are (rounds, K, lanes); witnesses name (step, lane, k)
+        oob = (cols < 0) | (cols > m)
+        if oob.any():
+            g, k, t = (int(x) for x in np.argwhere(oob)[0])
             out.append(Violation(
-                kind="premature-read", where=where, round=goff + g,
+                kind="index-out-of-range", where=where, round=g0 + g,
+                detail=f"cols[{g0 + g},{t},{k}] = {int(cols[g, k, t])} "
+                       f"outside [0, {m}]"))
+        pad_val = (cols == m) & (vals != 0)
+        if pad_val.any():
+            g, k, t = (int(x) for x in np.argwhere(pad_val)[0])
+            out.append(Violation(
+                kind="nonzero-pad-value", where=where, round=g0 + g,
+                detail=f"vals[{g0 + g},{t},{k}] = {vals[g, k, t]!r} on the "
+                       f"out-of-range pad position"))
+        live = (vals != 0) & (cols < m)
+        if half == "forward":
+            bad, word = live & (cols >= dest[:, None, :]), "below"
+        else:
+            bad, word = live & (cols <= dest[:, None, :]), "above"
+        for g, k, t in np.argwhere(bad)[:MAX_VIOLATIONS - len(out)]:
+            g, k, t = int(g), int(k), int(t)
+            src, dst = int(cols[g, k, t]), int(dest[g, t])
+            out.append(Violation(
+                kind="premature-read", where=where, round=g0 + g,
                 rows=(dst, src), edge=(src, dst),
                 detail=f"{half} half gathers position {src} at step "
-                       f"{goff + g}, not strictly {word} its destination "
+                       f"{g0 + g}, not strictly {word} its destination "
                        f"{dst}"))
         if len(out) >= MAX_VIOLATIONS:
             return out

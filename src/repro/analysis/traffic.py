@@ -113,31 +113,43 @@ def measured_slice_bytes(text: str) -> float:
 
 
 def _apply_static_bytes(plan) -> tuple[float, str]:
-    """Sliced bytes of one fused-sweep apply, from the table shapes.
+    """Sliced bytes of one round-major apply, from the table shapes.
 
-    Per fused step the sweep slices: cols (R*K int32) + vals (R*K item) +
-    dinv (R item) + the q read, y-destination read, y gather (R*K item)
-    and the y update write — exactly the slice-family ops the optimized
-    HLO exposes, so static == measured when nothing leaks.
+    Per step of a segment half the sweep slices: cols (R*K int32) + vals
+    (R*K item) + dinv (R item) + the right-hand-side read (q forward, y
+    backward), the y gather (R*K item) and the y update write — exactly
+    the slice-family ops the optimized HLO exposes, so static == measured
+    when nothing leaks.
     """
     t = plan._precond.tables
-    s2, r, k = t.cols.shape
     item = plan._np_dtype.itemsize
-    cidx = t.cols.dtype.itemsize
-    per_step = r * k * (cidx + 2 * item) + 4 * r * item
-    return float(s2 * per_step), \
-        f"2S={s2} steps x (R={r}, K={k}, {item}B items)"
+    total = 0.0
+    for h in t.fwd + t.bwd:
+        n, k, r = h.cols.shape
+        total += n * (r * k * (h.cols.dtype.itemsize + 2 * item)
+                      + 3 * r * item)
+    return total, (f"2S={2 * t.n_steps} steps in {2 * t.n_segments} loops "
+                   f"(K, R per half: "
+                   f"{[h.cols.shape[1:] for h in t.fwd + t.bwd]}, "
+                   f"{item}B items)")
 
 
 def _spmv_gather_bytes(plan) -> tuple[float, str]:
-    """The x[cols] gather of the packed SpMV: one item per packed slot.
+    """The x[cols] gather of the packed SpMV: one item per packed slot,
+    plus the ``[:n]`` trim when SELL pads the rows to whole slices.
     (The vals/cols streams are consumed straight from parameters — no
     slice op — so they are static-only terms.)"""
     import numpy as np
     slots = int(np.prod(plan._spmv_vals.shape))
     item = plan._np_dtype.itemsize
-    return float(slots * item), \
-        f"{slots} packed slots x {item}B ({plan.spmv_format})"
+    total = float(slots * item)
+    detail = f"{slots} packed slots x {item}B ({plan.spmv_format})"
+    if plan.spmv_format == "sell":
+        rows = plan._spmv_vals.shape[0] * plan._spmv_vals.shape[-1]
+        if rows != plan._spmv_n:
+            total += plan._spmv_n * item
+            detail += f" + [:{plan._spmv_n}] trim of {rows} padded rows"
+    return total, detail
 
 
 def traffic_report(plan, measure: bool = True) -> TrafficReport:
@@ -153,7 +165,7 @@ def traffic_report(plan, measure: bool = True) -> TrafficReport:
     item = plan._np_dtype.itemsize
     m = plan.slab_m
     t = plan._precond.tables
-    s2, r, k = t.cols.shape
+    table_slots = sum(h.cols.size for h in t.fwd + t.bwd)
     slots = int(np.prod(plan._spmv_vals.shape))
 
     apply_static, apply_detail = _apply_static_bytes(plan)
@@ -193,7 +205,7 @@ def traffic_report(plan, measure: bool = True) -> TrafficReport:
     )
     # FLOPs: 2 MACs per packed slot (SpMV), 2 per table slot + diag scale
     # (sweep), ~10 per row of vector work
-    flops = float(2 * slots + 2 * s2 * r * k + s2 * r + 10 * m)
+    flops = float(2 * slots + 2 * table_slots + 2 * m + 10 * m)
     total = float(sum(x.static_bytes for x in terms))
     return TrafficReport(
         label=f"{plan.layout}/{plan.backend}/{plan.spmv_format}",

@@ -20,15 +20,16 @@ from .plan import (ON_BREAKDOWN, SCHEDULERS, SetupBreakdown, SolverPlan,
                    build_plan)
 from .sell import (FusedRoundMajorTables, PackingIndexError, RoundMajorLayout,
                    RoundMajorTables,
-                   SellMatrix, StepTables, fuse_round_major, pack_ell,
-                   pack_factor, pack_factor_hbmc, pack_sell, pack_steps,
-                   permute_round_major, round_major_layout, rounds_bmc,
-                   rounds_hbmc, rounds_levelset, rounds_mc, rounds_natural,
-                   to_round_major)
+                   SellMatrix, StepTables, SweepTables, fuse_round_major,
+                   pack_ell, pack_factor, pack_factor_hbmc, pack_sell,
+                   pack_steps, permute_round_major, round_major_layout,
+                   rounds_bmc, rounds_hbmc, rounds_levelset, rounds_mc,
+                   rounds_natural, segment_bounds, to_round_major)
 from .smoothers import GSSmoother, build_gs_smoother, gs_solve
 from .solvers import (BatchedICCGReport, ICCGReport, solve_iccg,
                       solve_iccg_batched)
-from .trisolve import (BACKENDS, LAYOUTS, DeviceFusedTables, DeviceTables,
+from .trisolve import (BACKENDS, LAYOUTS, DeviceFusedTables, DeviceSweep,
+                       DeviceTables,
                        DistributedRoundMajorPreconditioner,
                        HBMCPreconditioner, RoundMajorPreconditioner,
                        backward_solve, backward_solve_batched,

@@ -9,9 +9,9 @@ lives in the plan stack:
                         apply is the fused round-major sweep with ONE
                         collective per round
     core/trisolve.py    ``DistributedRoundMajorPreconditioner`` /
-                        ``_dist_substitute_fused`` — the sharded fused
-                        fwd+bwd substitution (``shard_map`` over the lane
-                        axis)
+                        ``_dist_substitute_fused`` — the sharded fwd+bwd
+                        substitution (``shard_map`` over every segment's
+                        lane axis)
     core/iccg.py        ``make_sharded_spmv`` — row/slice-sharded ELL/SELL
                         SpMV with one all-gather per apply
 
@@ -19,8 +19,8 @@ Parallel-ordering semantics map onto the mesh exactly as the paper maps
 them onto threads (§4.4.3), one level up:
 
     color      -> sequential rounds (the fori_loop over fused steps)
-    level-1 blocks of a color -> *devices* (the mesh axis): the fused
-                  tables' lane axis R is sharded, so each device owns a
+    level-1 blocks of a color -> *devices* (the mesh axis): every
+                  segment's lane axis is sharded, so each device owns a
                   contiguous batch of level-1 blocks
     w lanes    -> VPU vector lanes within a device
 

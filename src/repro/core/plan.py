@@ -65,7 +65,7 @@ class ICCGReport:
     n_rounds: int           # sequential rounds per triangular solve
     setup_seconds: float
     solve_seconds: float
-    lane_occupancy: float   # mean live lanes / padded lanes per round
+    lane_occupancy: float   # live lanes / lane slots of the solve layout
     x: np.ndarray           # solution in ORIGINAL ordering (== result.x)
     backend: str = "xla"
     layout: str = "round_major"
@@ -75,6 +75,7 @@ class ICCGReport:
     # unless a wrapper adds its plan build to it)
     embed_seconds: float = 0.0
     extract_seconds: float = 0.0
+    n_segments: int = 1     # lane-width segments of the round-major sweep
 
 
 @dataclasses.dataclass
@@ -95,6 +96,7 @@ class BatchedICCGReport:
     scheduler: str = "coloring"
     embed_seconds: float = 0.0
     extract_seconds: float = 0.0
+    n_segments: int = 1
 
 
 @dataclasses.dataclass
@@ -447,8 +449,9 @@ class SolverPlan:
                                       pack=t3 - t2, total=t3 - t0,
                                       **(self._sysd.ordering_stages or {}))
         self.setup_count += 1
-        self.lane_occupancy = _occupancy_from_rounds(self._sysd.fwd_rounds,
-                                                     self._sysd.drop)
+        self.lane_occupancy = (
+            self._rm.lane_occupancy if self._rm is not None else
+            _occupancy_from_rounds(self._sysd.fwd_rounds, self._sysd.drop))
 
     # -- derived properties -------------------------------------------------
 
@@ -467,6 +470,12 @@ class SolverPlan:
     @property
     def n_rounds(self) -> int:
         return self._precond.n_rounds
+
+    @property
+    def n_segments(self) -> int:
+        """Lane-width segments of the round-major sweep (1 for the index
+        layout, whose rounds share one width)."""
+        return self._rm.n_segments if self._rm is not None else 1
 
     # -- setup internals ----------------------------------------------------
 
@@ -949,7 +958,8 @@ class SolverPlan:
             solve_seconds=t[SOLVE_PCG], lane_occupancy=self.lane_occupancy,
             x=x_out, backend=self.backend, layout=self.layout,
             spmv_backend=self.spmv_backend, scheduler=self.scheduler,
-            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT])
+            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT],
+            n_segments=self.n_segments)
 
     def solve(self, b: np.ndarray, rtol: float = 1e-7,
               maxiter: int = 10_000,
@@ -986,7 +996,8 @@ class SolverPlan:
             solve_seconds=t[SOLVE_PCG], lane_occupancy=self.lane_occupancy,
             x=x_out, backend=self.backend, layout=self.layout,
             spmv_backend=self.spmv_backend, scheduler=self.scheduler,
-            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT])
+            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT],
+            n_segments=self.n_segments)
 
     def solve_batched(self, b: np.ndarray, rtol: float = 1e-7,
                       maxiter: int = 10_000,
@@ -1019,7 +1030,8 @@ class SolverPlan:
             solve_seconds=t[SOLVE_PCG], lane_occupancy=self.lane_occupancy,
             x=x_out, backend=self.backend, layout=self.layout,
             spmv_backend=self.spmv_backend, scheduler=self.scheduler,
-            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT])
+            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT],
+            n_segments=self.n_segments)
 
 
 def build_plan(a: sp.spmatrix, method: str = "hbmc", block_size: int = 32,

@@ -65,10 +65,17 @@ class StepTables:
     n_slots: int       # n_final + 1 (scratch slot at the end)
     # per-step live row count (R_s <= R), for occupancy accounting
     live: np.ndarray   # (S,) int32
+    lane_multiple: int = 1   # R (and every segment width) is a multiple
 
     @property
     def shape(self):
         return self.rows.shape + (self.cols.shape[-1],)
+
+    @property
+    def live_k(self) -> np.ndarray:
+        """(S,) widest live row of each round (entries are left-packed, so
+        a slot column is live if any lane of the round uses it)."""
+        return (self.cols != self.n_slots - 1).any(axis=1).sum(axis=-1)
 
 
 def rounds_hbmc(ordering: HBMCOrdering, reverse: bool = False
@@ -201,7 +208,8 @@ def pack_steps(tri: sp.csr_matrix, diag: np.ndarray,
     vals[dst] = tri.data[src]
     return StepTables(rows=rows, cols=cols.reshape(S, R, K),
                       vals=vals.reshape(S, R, K), dinv=dinv,
-                      n_slots=n_slots, live=rlens.astype(np.int32))
+                      n_slots=n_slots, live=rlens.astype(np.int32),
+                      lane_multiple=lane_multiple)
 
 
 def pack_factor(l_final: sp.csr_matrix, fwd_rounds: list[np.ndarray],
@@ -236,10 +244,13 @@ def pack_factor_hbmc(l_final: sp.csr_matrix, ordering: HBMCOrdering
 class RoundMajorLayout:
     """The HBMC-index <-> round-major-position bijection (live lanes only).
 
-    Round-major is the execution-order coordinate system: lane ``t`` of
-    forward round ``s`` lives at position ``s * R + t`` of a dense ``(S*R,)``
-    vector.  Pad lanes (``rows == n_slots - 1``) are *holes*: they hold exact
-    zeros for the whole PCG loop and have no HBMC counterpart.
+    Round-major is the execution-order coordinate system.  The forward
+    rounds are cut into consecutive *segments* (``segment_bounds``):
+    segment ``c`` packs its ``n_c`` rounds at its own lane width ``R_c``
+    from flat offset ``o_c``, so lane ``t`` of its round ``j`` lives at
+    position ``o_c + j * R_c + t`` of a dense ``(m,)`` vector, ``m = sum_c
+    n_c * R_c``.  Pad lanes (``rows == n_slots - 1``) are *holes*: they
+    hold exact zeros for the whole PCG loop and have no HBMC counterpart.
 
     This object is the ONLY place permutations live in the round-major-native
     solver path: ``embed`` maps the right-hand side in once per solve,
@@ -247,51 +258,70 @@ class RoundMajorLayout:
     (SpMV, both triangular sweeps, all PCG state) stays in round-major
     coordinates.
     """
-    rows: np.ndarray   # (S, R) int32 — HBMC index per position (pad -> n_slots-1)
-    pos: np.ndarray    # (n_slots,) int64 — HBMC index -> position (none -> S*R)
+    rows: np.ndarray   # (m,) int32 — HBMC index per position (pad -> n_slots-1)
+    pos: np.ndarray    # (n_slots,) int64 — HBMC index -> position (none -> m)
     n_slots: int
+    segments: tuple[tuple[int, int], ...]   # (rounds, lanes) per segment
 
     @property
     def n_steps(self) -> int:
-        return self.rows.shape[0]
+        """Rounds per sweep, over all segments."""
+        return sum(n for n, _ in self.segments)
 
     @property
-    def lanes(self) -> int:
-        return self.rows.shape[1]
+    def n_segments(self) -> int:
+        return len(self.segments)
+
+    @property
+    def offsets(self) -> tuple[int, ...]:
+        """Flat position of each segment's first lane."""
+        sizes = [n * r for n, r in self.segments]
+        return tuple(int(o) for o in np.cumsum([0] + sizes[:-1]))
 
     @property
     def m(self) -> int:
-        """Padded round-major dimension S*R."""
+        """Padded round-major dimension, sum of rounds x lanes."""
         return self.rows.size
+
+    @property
+    def lane_occupancy(self) -> float:
+        """Live lanes / lane slots of the layout."""
+        live = np.count_nonzero(self.rows != self.n_slots - 1)
+        return live / self.m if self.m else 1.0
 
     def embed(self, v: np.ndarray) -> np.ndarray:
         """HBMC-ordered (n,) or (n, B) -> round-major (m,) / (m, B), holes 0."""
         v = np.asarray(v)
-        flat = self.rows.reshape(-1)
-        live = flat != self.n_slots - 1
+        live = self.rows != self.n_slots - 1
         out = np.zeros((self.m,) + v.shape[1:], dtype=v.dtype)
-        out[live] = v[flat[live]]
+        out[live] = v[self.rows[live]]
         return out
 
     def extract(self, y: np.ndarray) -> np.ndarray:
         """Round-major (m,) or (m, B) -> HBMC-ordered (n,) / (n, B)."""
         y = np.asarray(y)
-        flat = self.rows.reshape(-1)
-        live = flat != self.n_slots - 1
+        live = self.rows != self.n_slots - 1
         out = np.zeros((self.n_slots - 1,) + y.shape[1:], dtype=y.dtype)
-        out[flat[live]] = y[live]
+        out[self.rows[live]] = y[live]
         return out
 
 
+def _layout(segment_rows: list[np.ndarray], n_slots: int) -> RoundMajorLayout:
+    """Layout of consecutive segments, each an ``(n_c, R_c)`` block of
+    HBMC row indices (pad lanes ``n_slots - 1``)."""
+    rows = np.concatenate([r.reshape(-1) for r in segment_rows]
+                          ).astype(np.int32)
+    pos = np.full(n_slots, rows.size, dtype=np.int64)
+    live = rows != n_slots - 1
+    pos[rows[live]] = np.flatnonzero(live)
+    return RoundMajorLayout(rows=rows, pos=pos, n_slots=n_slots,
+                            segments=tuple(r.shape for r in segment_rows))
+
+
 def round_major_layout(t: StepTables) -> RoundMajorLayout:
-    """Layout induced by the forward StepTables (execution order)."""
-    s_, r_ = t.rows.shape
-    pos = np.full(t.n_slots, s_ * r_, dtype=np.int64)
-    lane = np.arange(s_ * r_).reshape(s_, r_)
-    live = t.rows != (t.n_slots - 1)
-    pos[t.rows[live]] = lane[live]
-    return RoundMajorLayout(rows=t.rows.astype(np.int32), pos=pos,
-                            n_slots=t.n_slots)
+    """Single-width layout of the forward StepTables (execution order):
+    one segment of all S rounds at the tables' lane width R."""
+    return _layout([t.rows], t.n_slots)
 
 
 @dataclasses.dataclass
@@ -333,70 +363,158 @@ def to_round_major(t: StepTables) -> RoundMajorTables:
     lay = round_major_layout(t)
     return RoundMajorTables(cols=lay.pos[t.cols].astype(np.int32),
                             vals=t.vals, dinv=t.dinv,
-                            rows=lay.rows, n_slots=t.n_slots)
+                            rows=t.rows.astype(np.int32), n_slots=t.n_slots)
+
+
+#: most segments a sweep is cut into (each runs two program loops an apply)
+MAX_SEGMENTS = 16
+#: padding a segment may gather, as a share of what its runs of equal-width
+#: rounds gather when each is packed alone
+SEGMENT_SLACK = 1 / 8
+
+
+def segment_bounds(live, k_fwd, k_bwd, lane_multiple: int = 1,
+                   max_segments: int = MAX_SEGMENTS) -> list[int]:
+    """Cut the forward rounds into segments; returns each segment's first
+    round, then S.
+
+    A segment packs its rounds at one lane width (its widest round, rounded
+    up to ``lane_multiple``) and one K per sweep half (its widest forward
+    row and its widest backward row), so an apply gathers ``rounds x width
+    x (K_fwd + K_bwd)`` values for it.  Consecutive rounds of one width
+    form a run, and cuts fall only between runs: rounds that all have one
+    width make one segment.  In forward order, a run joins the open
+    segment if the joined segment gathers at most ``SEGMENT_SLACK`` more
+    than its runs gather packed each alone; otherwise it opens a new
+    segment.  While that gives more than ``max_segments`` segments, the
+    slack doubles.  ``live`` is the rounds' live lane counts, ``k_fwd`` /
+    ``k_bwd`` their widest live rows in each half (forward round order).
+    """
+    lm = max(int(lane_multiple), 1)
+    width = -(-np.asarray(live, dtype=np.int64) // lm) * lm
+    kf = np.maximum(np.asarray(k_fwd, dtype=np.int64), 1)
+    kb = np.maximum(np.asarray(k_bwd, dtype=np.int64), 1)
+    s_ = len(width)
+    starts = np.concatenate([[0], np.flatnonzero(np.diff(width)) + 1, [s_]])
+    runs = [(int(a), b - a, int(width[a]), int(kf[a:b].max()),
+             int(kb[a:b].max()))
+            for a, b in zip(starts[:-1], starts[1:])]
+    slack = SEGMENT_SLACK
+    while True:
+        cuts, n, w, f, b, apart = [0], 0, 0, 0, 0, 0
+        for start, rn, rw, rf, rb in runs:
+            alone = rn * rw * (rf + rb)
+            joined = (n + rn) * max(w, rw) * (max(f, rf) + max(b, rb))
+            if n and joined > (1 + slack) * (apart + alone):
+                cuts.append(start)
+                n, w, f, b, apart = 0, 0, 0, 0, 0
+            n, w, f, b = n + rn, max(w, rw), max(f, rf), max(b, rb)
+            apart += alone
+        if len(cuts) <= max(max_segments, 1):
+            return cuts + [s_]
+        slack *= 2
+
+
+@dataclasses.dataclass
+class SweepTables:
+    """One sweep half of one segment: ``n`` rounds of ``R`` lanes in that
+    half's execution order.  ``cols`` are flat round-major gather
+    positions (missing -> ``m``, read via ``fill_value=0`` against zero
+    ``vals``).  Lanes are the minor axis, as in ``pack_ell``: inside the
+    PCG loop the TPU compiler may lay an ``(n, R, K)`` table out with K
+    (1 to about 5) padded to the 128-lane axis, up to 128 times its
+    bytes."""
+    cols: np.ndarray   # (n, K, R) int32
+    vals: np.ndarray   # (n, K, R)
+    dinv: np.ndarray   # (n, R)
 
 
 @dataclasses.dataclass
 class FusedRoundMajorTables:
-    """Forward AND backward sweeps packed for one fused 2S-step solve.
+    """Forward AND backward sweeps packed for one solve over one buffer.
 
     The backward rounds are exactly the forward rounds reversed (``rounds_*``
     build them that way, lane order included), so in *forward* round-major
-    coordinates the backward sweep's round ``s'`` writes the contiguous slice
-    ``[(S-1-s')*R, (S-s')*R)`` — a dense store, same as the forward sweep.
-    That makes one solution buffer sufficient: the forward half fills it with
-    ``y = L^{-1} q`` slice by slice, the backward half overwrites it in place
-    with ``z = L^{-T} y`` in reverse slice order.  Every value the backward
-    gather touches is either already overwritten (a ``z`` entry from a later
-    forward round — exactly its dependencies) or the current slice's ``y``
-    read before the store.
+    coordinates every backward round writes the contiguous slice its
+    forward round wrote — a dense store, same as the forward sweep.  That
+    makes one solution buffer sufficient: the forward half fills it with
+    ``y = L^{-1} q`` slice by slice, the backward half overwrites it in
+    place with ``z = L^{-T} y`` in reverse slice order.  Every value the
+    backward gather touches is either already overwritten (a ``z`` entry
+    from a later forward round — exactly its dependencies) or the current
+    slice's ``y`` read before the store.
 
-    Step ``g`` of the fused schedule uses table row ``g``: rows ``0..S-1``
-    are the forward rounds, rows ``S..2S-1`` the backward rounds in backward
-    execution order.  ``cols`` of BOTH halves are forward round-major gather
-    positions (missing -> ``m``, read via ``fill_value=0`` against zero
-    ``vals``).
+    Per segment ``c`` (``layout.segments``, ``layout.offsets``):
+    ``fwd[c]`` holds its forward rounds in order, round ``j`` writing
+    ``[o_c + j R_c, o_c + (j+1) R_c)``; ``bwd[c]`` its backward rounds in
+    backward execution order, step ``j`` writing the slice of forward
+    round ``n_c - 1 - j``.  The forward sweep runs the segments in order,
+    the backward sweep in reverse order.  Each half has its own K.
     """
-    cols: np.ndarray   # (2S, R, K) int32 — fwd-round-major gather positions
-    vals: np.ndarray   # (2S, R, K) f64
-    dinv: np.ndarray   # (2S, R) f64
+    fwd: list[SweepTables]
+    bwd: list[SweepTables]
     layout: RoundMajorLayout
 
     @property
     def n_steps(self) -> int:
-        """Rounds per sweep (the fused grid has 2 * n_steps steps)."""
+        """Rounds per sweep (an apply runs 2 * n_steps steps)."""
         return self.layout.n_steps
 
     @property
-    def shape(self):
-        return self.cols.shape
+    def n_segments(self) -> int:
+        return len(self.fwd)
 
 
-def fuse_round_major(fwd: StepTables, bwd: StepTables) -> FusedRoundMajorTables:
-    """Pack forward + backward StepTables into the fused round-major form."""
+def stack_sweeps(fwd, bwd, m: int, xp=np):
+    """One segment's halves as single ``(2S, R, K)`` cols / vals and
+    ``(2S, R)`` dinv — the Pallas fused kernel's operands: the forward
+    rounds, then the backward rounds in backward order, both padded to the
+    wider K (pad cols ``m``, pad vals 0).  ``xp`` is ``np`` or ``jnp``."""
+    k = max(fwd.cols.shape[1], bwd.cols.shape[1])
+
+    def pad(x, fill):
+        x = xp.pad(x, ((0, 0), (0, k - x.shape[1]), (0, 0)),
+                   constant_values=fill)
+        return xp.swapaxes(x, 1, 2)
+
+    return (xp.concatenate([pad(fwd.cols, m), pad(bwd.cols, m)]),
+            xp.concatenate([pad(fwd.vals, 0), pad(bwd.vals, 0)]),
+            xp.concatenate([fwd.dinv, bwd.dinv]))
+
+
+def fuse_round_major(fwd: StepTables, bwd: StepTables,
+                     max_segments: int = MAX_SEGMENTS
+                     ) -> FusedRoundMajorTables:
+    """Pack forward + backward StepTables into the segmented round-major
+    form (``segment_bounds`` cuts the rounds; ``max_segments=1`` gives the
+    single-width packing)."""
     if fwd.rows.shape != bwd.rows.shape or fwd.n_slots != bwd.n_slots:
         raise ValueError("forward/backward tables disagree on round shape")
     if not np.array_equal(bwd.rows[::-1], fwd.rows):
         raise ValueError("backward rounds must be the reversed forward "
                          "rounds (lane order included)")
-    lay = round_major_layout(fwd)
-    m = lay.m
-    k = max(fwd.cols.shape[-1], bwd.cols.shape[-1])
+    s_ = fwd.rows.shape[0]
+    kf, kb = fwd.live_k, bwd.live_k[::-1]
+    bounds = segment_bounds(fwd.live, kf, kb, fwd.lane_multiple,
+                            max_segments)
+    lm = fwd.lane_multiple
+    spans = [(a, b, -(-int(fwd.live[a:b].max(initial=0)) // lm) * lm)
+             for a, b in zip(bounds[:-1], bounds[1:])]
+    lay = _layout([fwd.rows[a:b, :w] for a, b, w in spans], fwd.n_slots)
 
-    def half(t: StepTables) -> tuple[np.ndarray, np.ndarray]:
-        s_, r_, kt = t.cols.shape
-        cols = np.full((s_, r_, k), m, dtype=np.int32)
-        vals = np.zeros((s_, r_, k), dtype=t.vals.dtype)
-        cols[:, :, :kt] = lay.pos[t.cols]
-        vals[:, :, :kt] = t.vals
-        return cols, vals
+    def half(t: StepTables, a: int, b: int, w: int, k: int) -> SweepTables:
+        def kr(x):
+            return np.ascontiguousarray(x[a:b, :w, :k].transpose(0, 2, 1))
+        return SweepTables(cols=lay.pos[kr(t.cols)].astype(np.int32),
+                           vals=kr(t.vals), dinv=t.dinv[a:b, :w].copy())
 
-    fc, fv = half(fwd)
-    bc, bv = half(bwd)
+    def k_of(k: np.ndarray, a: int, b: int) -> int:
+        return max(int(k[a:b].max(initial=0)), 1)
+
     return FusedRoundMajorTables(
-        cols=np.concatenate([fc, bc], axis=0),
-        vals=np.concatenate([fv, bv], axis=0),
-        dinv=np.concatenate([fwd.dinv, bwd.dinv], axis=0),
+        fwd=[half(fwd, a, b, w, k_of(kf, a, b)) for a, b, w in spans],
+        bwd=[half(bwd, s_ - b, s_ - a, w, k_of(kb, a, b))
+             for a, b, w in spans],
         layout=lay)
 
 

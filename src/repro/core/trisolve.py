@@ -19,8 +19,9 @@ And two PCG-loop layouts:
   * ``HBMCPreconditioner`` (``layout="index"``) applies in permuted-matrix
     index space — the solve layout is re-gathered/scattered per apply.
   * ``RoundMajorPreconditioner`` (``layout="round_major"``, the default
-    solver path) applies natively on round-major vectors with both sweeps
-    fused into one 2S-step pass; zero per-apply permutations.
+    solver path) applies natively on round-major vectors, both sweeps over
+    one buffer (a loop per segment and half, each segment packed at its
+    own lane width); zero per-apply permutations.
 
 All variants expose a multi-RHS path (``apply_batched``) consumed by the
 batched PCG front-end (``iccg.pcg_batched``).
@@ -43,8 +44,9 @@ import scipy.sparse as sp
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .hbmc import HBMCOrdering
-from .sell import (FusedRoundMajorTables, RoundMajorLayout, StepTables,
-                   fuse_round_major, pack_factor_hbmc)
+from .sell import (MAX_SEGMENTS, FusedRoundMajorTables, RoundMajorLayout,
+                   StepTables, fuse_round_major, pack_factor_hbmc,
+                   stack_sweeps)
 
 BACKENDS = ("xla", "pallas")
 LAYOUTS = ("round_major", "index")
@@ -152,20 +154,16 @@ def backward_solve_batched(tables: DeviceTables, y: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Round-major-native path: the PCG state itself lives in round-major
 # coordinates, so the preconditioner apply performs ZERO permutations and
-# both sweeps run as one fused pass (2S steps over one buffer).
+# both sweeps run over one buffer, one program loop per segment and half.
 # ---------------------------------------------------------------------------
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
-class DeviceFusedTables:
-    """sell.FusedRoundMajorTables moved to device as a pytree.
-
-    Row ``g`` of each array drives fused step ``g``: forward rounds for
-    ``g < S``, backward rounds (backward execution order) for ``g >= S``.
-    """
-    cols: jax.Array   # (2S, R, K) int32 — fwd-round-major gather positions
-    vals: jax.Array   # (2S, R, K)
-    dinv: jax.Array   # (2S, R)
+class DeviceSweep:
+    """sell.SweepTables moved to device: one half of one segment."""
+    cols: jax.Array   # (n, K, R) int32 — flat round-major gather positions
+    vals: jax.Array   # (n, K, R)
+    dinv: jax.Array   # (n, R)
 
     def tree_flatten(self):
         return (self.cols, self.vals, self.dinv), ()
@@ -174,85 +172,141 @@ class DeviceFusedTables:
     def tree_unflatten(cls, aux, children):
         return cls(*children)
 
-    @property
-    def n_steps(self) -> int:
-        """Rounds per sweep (the fused loop runs 2 * n_steps steps)."""
-        return self.dinv.shape[0] // 2
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class DeviceFusedTables:
+    """sell.FusedRoundMajorTables moved to device as a pytree.
+
+    ``fwd[c]`` / ``bwd[c]`` are segment ``c``'s forward rounds and its
+    backward rounds (backward execution order); the segments' offsets in
+    the flat state follow from their shapes.
+    """
+    fwd: tuple[DeviceSweep, ...]
+    bwd: tuple[DeviceSweep, ...]
+
+    def tree_flatten(self):
+        return (self.fwd, self.bwd), ()
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
 
     @property
-    def lanes(self) -> int:
-        return self.dinv.shape[1]
+    def segments(self) -> tuple[tuple[int, int], ...]:
+        """(rounds, lanes) per segment."""
+        return tuple(t.dinv.shape for t in self.fwd)
+
+    @property
+    def n_segments(self) -> int:
+        return len(self.fwd)
+
+    @property
+    def n_steps(self) -> int:
+        """Rounds per sweep (an apply runs 2 * n_steps steps)."""
+        return sum(n for n, _ in self.segments)
+
+    @property
+    def m(self) -> int:
+        """Length of the round-major state."""
+        return sum(n * r for n, r in self.segments)
+
+    def stacked(self) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """The single segment as the Pallas fused kernel's ``(2S, R, K)``
+        operands (``sell.stack_sweeps``)."""
+        if self.n_segments != 1:
+            raise ValueError(f"the Pallas fused kernel runs one segment, "
+                             f"these tables have {self.n_segments}")
+        return stack_sweeps(self.fwd[0], self.bwd[0], self.m, xp=jnp)
 
     @classmethod
     def from_host(cls, f: FusedRoundMajorTables,
                   dtype=jnp.float64) -> "DeviceFusedTables":
-        return cls(cols=jnp.asarray(f.cols),
-                   vals=jnp.asarray(f.vals, dtype=dtype),
-                   dinv=jnp.asarray(f.dinv, dtype=dtype))
+        def dev(t):
+            return DeviceSweep(cols=jnp.asarray(t.cols),
+                               vals=jnp.asarray(t.vals, dtype=dtype),
+                               dinv=jnp.asarray(t.dinv, dtype=dtype))
+        return cls(fwd=tuple(map(dev, f.fwd)), bwd=tuple(map(dev, f.bwd)))
 
 
-def _substitute_fused(tables: DeviceFusedTables, q: jax.Array) -> jax.Array:
-    """Fused fwd+bwd substitution in round-major coordinates.  q: (S, R).
+def _sweeps(tables: DeviceFusedTables, q: jax.Array,
+            segments: tuple[tuple[int, int], ...],
+            axis: str | None = None) -> jax.Array:
+    """z = (L L^T)^{-1} q in round-major coordinates.  q: (m,) or (m, B).
 
-    The round-major ``_substitute``: each step's store is a dense
-    ``lax.dynamic_update_slice`` instead of the ``y.at[rows].set`` scatter
-    of the index-space path — the backward half overwrites the forward
-    result in place, in reverse slice order (see kernels/hbmc_trisolve.py
-    for the safety argument).  Zero scatter ops in the jaxpr.
+    One ``fori_loop`` per segment and half over one flat buffer: the
+    forward loops in segment order, then the backward loops in reverse
+    order, each step gathering, contracting and storing one round as a
+    dense ``lax.dynamic_update_slice`` — the backward half overwrites the
+    forward result in place, in reverse slice order (see
+    ``sell.FusedRoundMajorTables`` for the safety argument).  Zero scatter
+    ops in the jaxpr.  ``segments`` gives each segment's (rounds, lanes)
+    at full width; under ``shard_map`` over ``axis`` the tables hold this
+    device's lane block and one tiled all-gather per step assembles the
+    round before its store.
     """
-    s_, r_ = q.shape
-    s2 = 2 * s_
-    y0 = jnp.zeros((s_ * r_,), dtype=q.dtype)
+    tail = q.shape[1:]                            # () or (B,)
+    # einsum (not elementwise-multiply + sum): XLA contracts it directly
+    # instead of materializing the product — measurably faster on CPU.
+    # The kernel-exact op order lives in kernels/ref.py instead.
+    eq = "kr,krb->rb" if tail else "kr,kr->r"
+    shard = None if axis is None else jax.lax.axis_index(axis)
+    offsets = np.cumsum([0] + [n * r for n, r in segments[:-1]])
 
-    def body(g, y):
-        gathered = jnp.take(y, tables.cols[g], axis=0, fill_value=0)  # (R, K)
-        # einsum (not elementwise-multiply + sum): XLA contracts it directly
-        # instead of materializing the product — measurably faster on CPU.
-        # The kernel-exact op order lives in kernels/ref.py instead.
-        acc = jnp.einsum("rk,rk->r", tables.vals[g], gathered)
-        dest = jnp.where(g < s_, g, s2 - 1 - g) * r_
-        q_cur = jnp.where(g < s_, q[jnp.minimum(g, s_ - 1)],
-                          jax.lax.dynamic_slice(y, (dest,), (r_,)))
-        t = (q_cur - acc) * tables.dinv[g]
-        return jax.lax.dynamic_update_slice(y, t, (dest,))
+    def loop(t: DeviceSweep, off: int, r_full: int, y, backward: bool):
+        n, r_loc = t.dinv.shape
 
-    return jax.lax.fori_loop(0, s2, body, y0)
+        def body(j, y):
+            vals = t.vals[j]
+            gathered = jnp.take(y, t.cols[j], axis=0, fill_value=0)
+            if r_loc == 1 < r_full:
+                # XLA lowers a one-lane contraction as a plain dot, which
+                # sums in another order than the full round's batched one:
+                # contract this device's lane beside a zero lane instead
+                pad = ((0, 0), (0, 1)) + ((0, 0),) * len(tail)
+                acc = jnp.einsum(eq, jnp.pad(vals, pad[:2]),
+                                 jnp.pad(gathered, pad))[:1]
+            else:
+                acc = jnp.einsum(eq, vals, gathered)
+            # pin the index dtype: the loop counter is weakly typed and
+            # axis_index is i32 — mixing them flips dtypes between the
+            # dynamic_slice index operands
+            dest = (int(off) + ((n - 1 - j) if backward else j) * r_full
+                    ).astype(jnp.int32)
+            zeros = (jnp.zeros_like(dest),) * len(tail)
+            mine = dest if axis is None else dest + shard * r_loc
+            # forward rounds read their q slice; backward rounds the y
+            # slice they are about to overwrite
+            q_cur = jax.lax.dynamic_slice(y if backward else q,
+                                          (mine,) + zeros, (r_loc,) + tail)
+            d = t.dinv[j][:, None] if tail else t.dinv[j]
+            new = (q_cur - acc) * d
+            if axis is not None:
+                new = jax.lax.all_gather(new, axis, tiled=True)
+            return jax.lax.dynamic_update_slice(y, new, (dest,) + zeros)
 
+        return jax.lax.fori_loop(0, n, body, y)
 
-def _substitute_fused_batched(tables: DeviceFusedTables,
-                              q: jax.Array) -> jax.Array:
-    """Multi-RHS fused substitution.  q: (S, R, B) -> (S*R, B)."""
-    s_, r_, b_ = q.shape
-    s2 = 2 * s_
-    y0 = jnp.zeros((s_ * r_, b_), dtype=q.dtype)
-
-    def body(g, y):
-        gathered = jnp.take(y, tables.cols[g], axis=0, fill_value=0)
-        acc = jnp.einsum("rk,rkb->rb", tables.vals[g], gathered)
-        dest = jnp.where(g < s_, g, s2 - 1 - g) * r_
-        q_cur = jnp.where(g < s_, q[jnp.minimum(g, s_ - 1)],
-                          jax.lax.dynamic_slice(y, (dest, jnp.zeros_like(dest)), (r_, b_)))
-        t = (q_cur - acc) * tables.dinv[g][:, None]
-        return jax.lax.dynamic_update_slice(y, t, (dest, jnp.zeros_like(dest)))
-
-    return jax.lax.fori_loop(0, s2, body, y0)
+    y = jnp.zeros(q.shape, dtype=q.dtype)
+    for t, off, (_, r) in zip(tables.fwd, offsets, segments):
+        y = loop(t, off, r, y, backward=False)
+    for t, off, (_, r) in reversed(list(zip(tables.bwd, offsets, segments))):
+        y = loop(t, off, r, y, backward=True)
+    return y
 
 
 @jax.jit
 def fused_solve(tables: DeviceFusedTables, q: jax.Array) -> jax.Array:
-    """z = (L L^T)^{-1} q, round-major in and out.  q: (S, R) -> (S*R,)."""
-    return _substitute_fused(tables, q)
+    """z = (L L^T)^{-1} q, round-major in and out.  q: (m,) or (m, B)."""
+    return _sweeps(tables, q, tables.segments)
 
 
-@jax.jit
-def fused_solve_batched(tables: DeviceFusedTables, q: jax.Array) -> jax.Array:
-    """Multi-RHS fused apply.  q: (S, R, B) -> (S*R, B)."""
-    return _substitute_fused_batched(tables, q)
+fused_solve_batched = fused_solve     # the multi-RHS apply, q: (m, B)
 
 
 # ---------------------------------------------------------------------------
-# Mesh-sharded fused substitution: the lane axis R is sharded over one mesh
-# axis, the solution vector is replicated, and each fused step ends in ONE
+# Mesh-sharded substitution: every segment's lane axis is sharded over one
+# mesh axis, the solution vector is replicated, and each step ends in ONE
 # tiled all-gather of the lane updates — the distributed analogue of the
 # paper's "one synchronization per color" (§4.4.3), one level up: level-1
 # blocks -> devices, w lanes -> the vector unit within a device.
@@ -272,69 +326,44 @@ def auto_mesh(mesh: Mesh) -> Mesh:
                 axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
-def _dist_substitute_fused(mesh: Mesh, axis: str, m: int,
-                           cols: jax.Array, vals: jax.Array,
-                           dinv: jax.Array, q: jax.Array,
-                           batched: bool) -> jax.Array:
-    """Fused fwd+bwd sweep with the lane axis sharded over ``axis``.
+def _lane_specs(tables: DeviceFusedTables, axis: str):
+    """PartitionSpecs sharding every table's lane axis over ``axis``."""
+    return jax.tree.map(
+        lambda x: P(None, None, axis) if x.ndim == 3 else P(None, axis),
+        tables)
 
-    ``cols``/``vals``: (2S, R, K) with R a multiple of the axis size;
-    ``dinv``: (2S, R); ``q``: (S, R) (or (S, R, B)).  Per fused step, every
-    device computes its own lane block's updates (gathering from its
-    replica of y) and one ``all_gather(tiled=True)`` assembles the round's
-    dense slice before the store — the per-lane arithmetic is exactly
-    ``_substitute_fused``'s, so results are bitwise identical to the
-    single-device sweep over the same tables.
+
+def _dist_substitute_fused(mesh: Mesh, axis: str, tables: DeviceFusedTables,
+                           q: jax.Array) -> jax.Array:
+    """The round-major apply with every segment's lane axis sharded over
+    ``axis`` (each segment's lanes a multiple of the axis size).
+
+    ``q``: (m,) or (m, B), replicated.  Per step, every device computes its
+    own lane block's updates (gathering from its replica of y) and one
+    ``all_gather(tiled=True)`` assembles the round's dense slice before the
+    store — the per-lane arithmetic is exactly the single-device sweep's,
+    so results are bitwise identical to it over the same tables.
     """
-    r_full = dinv.shape[1]
-    t_spec = (P(None, axis, None), P(None, axis, None), P(None, axis))
-    q_spec = P(None, axis, None) if batched else P(None, axis)
+    segments = tables.segments
 
-    @partial(jax.shard_map, mesh=mesh, in_specs=t_spec + (q_spec,),
-             out_specs=P(), check_vma=False)
-    def solve(cols_l, vals_l, dinv_l, q_l):
-        s_ = q_l.shape[0]
-        r_loc = dinv_l.shape[1]
-        s2 = 2 * s_
-        tail = q_l.shape[2:]                      # () or (B,)
-        y0 = jnp.zeros((m,) + tail, dtype=q_l.dtype)
-        i = jax.lax.axis_index(axis)
-        eq = "rk,rkb->rb" if batched else "rk,rk->r"
+    @partial(jax.shard_map, mesh=mesh,
+             in_specs=(_lane_specs(tables, axis), P()), out_specs=P(),
+             check_vma=False)
+    def solve(tables_l, q_l):
+        return _sweeps(tables_l, q_l, segments, axis=axis)
 
-        def body(g, y):
-            gathered = jnp.take(y, cols_l[g], axis=0, fill_value=0)
-            acc = jnp.einsum(eq, vals_l[g], gathered)
-            # pin the index dtype: the loop counter is weakly typed and
-            # axis_index is i32 — mixing them flips dtypes between the
-            # dynamic_slice index operands
-            dest = (jnp.where(g < s_, g, s2 - 1 - g) * r_full
-                    ).astype(jnp.int32)
-            zeros = (jnp.zeros_like(dest),) * len(tail)
-            # forward half reads its lane block of q; backward half reads
-            # the y slice it is about to overwrite (see _substitute_fused)
-            q_cur = jnp.where(
-                g < s_, q_l[jnp.minimum(g, s_ - 1)],
-                jax.lax.dynamic_slice(
-                    y, (dest + i * r_loc,) + zeros, (r_loc,) + tail))
-            d = dinv_l[g][:, None] if batched else dinv_l[g]
-            t = (q_cur - acc) * d
-            t_full = jax.lax.all_gather(t, axis, tiled=True)
-            return jax.lax.dynamic_update_slice(y, t_full, (dest,) + zeros)
-
-        return jax.lax.fori_loop(0, s2, body, y0)
-
-    return solve(cols, vals, dinv, q)
+    return solve(tables, q)
 
 
 @dataclasses.dataclass(frozen=True)
 class DistributedRoundMajorPreconditioner:
     """``RoundMajorPreconditioner`` sharded over a device mesh axis.
 
-    ``tables`` hold the fused round-major form with the LANE axis sharded
-    over ``mesh``/``axis`` (``NamedSharding(mesh, P(None, axis, None))``
+    ``tables`` hold the round-major segments with every LANE axis sharded
+    over ``mesh``/``axis`` (``NamedSharding(mesh, P(None, None, axis))``
     for cols/vals, ``P(None, axis)`` for dinv) — the heavy data is fully
-    distributed; the (m,) state vectors stay replicated.  The apply is the
-    fused single-pass 2S-step sweep with one collective per round.
+    distributed; the (m,) state vectors stay replicated.  The apply runs
+    the segments' loops with one collective per round.
     """
     tables: DeviceFusedTables
     mesh: Mesh
@@ -346,47 +375,33 @@ class DistributedRoundMajorPreconditioner:
 
     @property
     def m(self) -> int:
-        return self.tables.n_steps * self.tables.lanes
-
-    def _reshape(self, r: jax.Array, batched: bool) -> jax.Array:
-        s_, lanes = self.tables.n_steps, self.tables.lanes
-        shape = (s_, lanes) + ((r.shape[-1],) if batched else ())
-        return r.reshape(shape)
+        return self.tables.m
 
     def __call__(self, r: jax.Array) -> jax.Array:
-        t = self.tables
-        return _dist_substitute_fused(self.mesh, self.axis, self.m, t.cols,
-                                      t.vals, t.dinv,
-                                      self._reshape(r, batched=False),
-                                      batched=False)
+        return _dist_substitute_fused(self.mesh, self.axis, self.tables, r)
 
     def apply_batched(self, r: jax.Array) -> jax.Array:
-        t = self.tables
-        return _dist_substitute_fused(self.mesh, self.axis, self.m, t.cols,
-                                      t.vals, t.dinv,
-                                      self._reshape(r, batched=True),
-                                      batched=True)
+        return _dist_substitute_fused(self.mesh, self.axis, self.tables, r)
 
 
 def shard_fused_tables(tables: DeviceFusedTables, mesh: Mesh,
                        axis: str = "data") -> DeviceFusedTables:
-    """Place fused tables with the lane axis sharded over ``axis``.
+    """Place round-major tables with every lane axis sharded over ``axis``.
 
-    The lane axis must already be a multiple of the axis size — build the
-    plan/tables with ``lane_multiple = mesh.shape[axis]``
+    Each segment's lane axis must already be a multiple of the axis size —
+    build the plan/tables with ``lane_multiple = mesh.shape[axis]``
     (``pack_steps(..., lane_multiple=...)``) rather than re-padding here,
     so every round-major position stays valid.
     """
     n_dev = mesh.shape[axis]
-    if tables.lanes % n_dev != 0:
-        raise ValueError(
-            f"lane axis ({tables.lanes}) is not a multiple of mesh axis "
-            f"{axis!r} ({n_dev}); pack with lane_multiple={n_dev}")
-    sh3 = NamedSharding(mesh, P(None, axis, None))
-    sh2 = NamedSharding(mesh, P(None, axis))
-    return DeviceFusedTables(cols=jax.device_put(tables.cols, sh3),
-                             vals=jax.device_put(tables.vals, sh3),
-                             dinv=jax.device_put(tables.dinv, sh2))
+    for _, lanes in tables.segments:
+        if lanes % n_dev != 0:
+            raise ValueError(
+                f"lane axis ({lanes}) is not a multiple of mesh axis "
+                f"{axis!r} ({n_dev}); pack with lane_multiple={n_dev}")
+    return jax.tree.map(
+        lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec)),
+        tables, _lane_specs(tables, axis))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -398,9 +413,10 @@ class RoundMajorPreconditioner:
     and output ARE round-major: the only permutations of a solve happen in
     ``RoundMajorLayout.embed``/``extract``, once each, outside the PCG loop.
 
-    ``backend="xla"`` runs ``fused_solve`` (fori_loop, dynamic slices);
-    ``backend="pallas"`` runs ``kernels.hbmc_trisolve_fused`` (one
-    pallas_call, 2S-step sequential grid, y VMEM-resident across sweeps).
+    ``backend="xla"`` runs ``fused_solve`` (a loop per segment and half,
+    dynamic slices); ``backend="pallas"`` runs
+    ``kernels.hbmc_trisolve_fused`` (one pallas_call, 2S-step sequential
+    grid, y VMEM-resident across sweeps) on single-segment tables.
     """
     tables: DeviceFusedTables
     backend: str = "xla"
@@ -412,30 +428,27 @@ class RoundMajorPreconditioner:
 
     @property
     def m(self) -> int:
-        return self.tables.n_steps * self.tables.lanes
+        return self.tables.m
 
-    def _reshape(self, r: jax.Array, batched: bool) -> jax.Array:
-        s_, lanes = self.tables.n_steps, self.tables.lanes
+    def _pallas_operands(self, r: jax.Array, batched: bool):
+        cols, vals, dinv = self.tables.stacked()
+        s_, lanes = self.tables.segments[0]
         shape = (s_, lanes) + ((r.shape[-1],) if batched else ())
-        return r.reshape(shape)
+        return cols, vals, dinv, r.reshape(shape)
 
     def __call__(self, r: jax.Array) -> jax.Array:
-        q = self._reshape(r, batched=False)
         if self.backend == "pallas":
             from repro.kernels.hbmc_trisolve import hbmc_trisolve_fused
-            return hbmc_trisolve_fused(self.tables.cols, self.tables.vals,
-                                       self.tables.dinv, q,
+            return hbmc_trisolve_fused(*self._pallas_operands(r, False),
                                        interpret=self.interpret)
-        return fused_solve(self.tables, q)
+        return fused_solve(self.tables, r)
 
     def apply_batched(self, r: jax.Array) -> jax.Array:
-        q = self._reshape(r, batched=True)
         if self.backend == "pallas":
             from repro.kernels.hbmc_trisolve import hbmc_trisolve_fused_batched
             return hbmc_trisolve_fused_batched(
-                self.tables.cols, self.tables.vals, self.tables.dinv, q,
-                interpret=self.interpret)
-        return fused_solve_batched(self.tables, q)
+                *self._pallas_operands(r, True), interpret=self.interpret)
+        return fused_solve_batched(self.tables, r)
 
 
 def build_round_major_preconditioner_from_rounds(
@@ -443,18 +456,22 @@ def build_round_major_preconditioner_from_rounds(
         dtype=jnp.float64, backend: str = "xla",
         interpret: bool | None = None, lane_multiple: int = 1
         ) -> tuple[RoundMajorPreconditioner, RoundMajorLayout]:
-    """Pack a factor into the fused round-major form; returns the native
-    preconditioner plus the layout (the b-in / x-out permutation pair).
+    """Pack a factor into the segmented round-major form; returns the
+    native preconditioner plus the layout (the b-in / x-out permutation
+    pair).  The Pallas fused kernel takes uniform tables, so its plans
+    keep one segment.
 
-    ``lane_multiple`` pads the lane axis so it shards evenly over a mesh
-    axis of that size (see ``DistributedRoundMajorPreconditioner``)."""
+    ``lane_multiple`` pads every segment's lane axis so it shards evenly
+    over a mesh axis of that size (see
+    ``DistributedRoundMajorPreconditioner``)."""
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; expected one of "
                          f"{BACKENDS}")
     from .sell import pack_factor
     fwd_h, bwd_h = pack_factor(l_final, fwd_rounds, bwd_rounds, drop_mask,
                                lane_multiple)
-    fused_h = fuse_round_major(fwd_h, bwd_h)
+    fused_h = fuse_round_major(
+        fwd_h, bwd_h, max_segments=1 if backend == "pallas" else MAX_SEGMENTS)
     pre = RoundMajorPreconditioner(
         tables=DeviceFusedTables.from_host(fused_h, dtype=dtype),
         backend=backend, interpret=interpret)

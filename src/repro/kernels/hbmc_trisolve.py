@@ -216,7 +216,8 @@ def hbmc_trisolve_fused(cols: jax.Array, vals: jax.Array, dinv: jax.Array,
     Args:
       cols: (2S, R, K) int32 — forward round-major gather positions; rows
         0..S-1 are the forward rounds, S..2S-1 the backward rounds in
-        backward execution order (``sell.fuse_round_major``).
+        backward execution order (``sell.stack_sweeps`` of the one
+        segment of ``sell.fuse_round_major(..., max_segments=1)``).
       vals: (2S, R, K) — off-diagonal values (0 on padding).
       dinv: (2S, R) — inverse diagonal (0 on padding lanes).
       q:    (S, R) — right-hand side in round-major layout.

@@ -67,7 +67,7 @@ def hbmc_trisolve_fused_ref(cols: jax.Array, vals: jax.Array,
     y slice by slice, backward half overwrites it in place in reverse slice
     order (see kernels/hbmc_trisolve.py for why that is safe).
 
-    Deliberately NOT shared with core.trisolve._substitute_fused: this
+    Deliberately NOT shared with core.trisolve._sweeps: this
     oracle reproduces the kernel's exact op order (elementwise multiply +
     jnp.sum -> bit-exact in interpret mode, asserted in tests), while the
     XLA production path contracts with einsum, which is faster on CPU but

@@ -149,10 +149,10 @@ def test_fused_table_self_read_is_witnessed():
     assert check_fused_tables(fused) == []
     lay = fused.layout
     g, t = 1, 0
-    assert lay.rows[g, t] != lay.n_slots - 1
-    pos = g * lay.lanes + t
-    fused.cols[g, t, 0] = pos               # forward half reads its own slot
-    fused.vals[g, t, 0] = 1.0
+    pos = g * lay.segments[0][1] + t        # segment 0 starts at 0
+    assert lay.rows[pos] != lay.n_slots - 1
+    fused.fwd[0].cols[g, 0, t] = pos        # forward half reads its own slot
+    fused.fwd[0].vals[g, 0, t] = 1.0
     vio = check_fused_tables(fused)
     assert any(v.kind == "premature-read" and v.edge == (pos, pos)
                for v in vio), [str(v) for v in vio]
@@ -280,10 +280,7 @@ def test_kernel_checks_catch_corruption_and_vmem():
     plan = build_plan(laplace_2d(10, 8), method="hbmc", block_size=8, w=4,
                       spmv_format="sell", backend="pallas",
                       spmv_backend="pallas", interpret=True)
-    t = plan._precond.tables
-    cols = np.asarray(t.cols).copy()
-    vals = np.asarray(t.vals).copy()
-    dinv = np.asarray(t.dinv)
+    cols, vals, dinv = (np.array(x) for x in plan._precond.tables.stacked())
     m = (cols.shape[0] // 2) * cols.shape[1]
     assert check_trisolve_fused(cols, vals, dinv) == []
     vio = check_trisolve_fused(cols, vals, dinv, vmem_budget=1024)

@@ -130,8 +130,9 @@ def test_validate_deep_gates_build_and_cache():
 # 2. Collective structure: synthetic-HLO mutations pinned, plans proven.
 # ---------------------------------------------------------------------------
 
-# the sweep shape the linter must accept: one while body, trip 2S, one
-# tiled all-gather (4 participants: f64[2] operand -> f64[8] result)
+# the sweep shape the linter must accept for one segment of S = 3 rounds:
+# a forward and a backward while body, trip S each, one tiled all-gather
+# per body (4 participants: f64[2] operand -> f64[8] result)
 GOOD_HLO = """\
 HloModule sweep_test
 
@@ -148,11 +149,20 @@ HloModule sweep_test
   ROOT %r = (f64[8]{0}) tuple(%ag)
 }
 
+%back_body (carg2: (f64[8])) -> (f64[8]) {
+  %ba2 = (f64[8]{0}) parameter(0)
+  %x2 = f64[8]{0} get-tuple-element(%ba2), index=0
+  %src2 = f64[2]{0} dynamic-slice(%x2, %x2), dynamic_slice_sizes={2}
+  %agb = f64[8]{0} all-gather(%src2), replica_groups={{0,1,2,3}}, dimensions={0}
+  ROOT %r2 = (f64[8]{0}) tuple(%agb)
+}
+
 ENTRY %main (p: f64[8]) -> f64[8] {
   %p1 = f64[8]{0} parameter(0)
   %t = (f64[8]{0}) tuple(%p1)
-  %w = (f64[8]{0}) while(%t), condition=%cond, body=%loop_body, backend_config={"known_trip_count":{"n":"6"}}
-  ROOT %out = f64[8]{0} get-tuple-element(%w), index=0
+  %w = (f64[8]{0}) while(%t), condition=%cond, body=%loop_body, backend_config={"known_trip_count":{"n":"3"}}
+  %w2 = (f64[8]{0}) while(%w), condition=%cond, body=%back_body, backend_config={"known_trip_count":{"n":"3"}}
+  ROOT %out = f64[8]{0} get-tuple-element(%w2), index=0
 }
 """
 
@@ -163,9 +173,9 @@ EXTRA_GATHER_LINE = ("  %ag2 = f64[8]{0} all-gather(%src), "
 def test_good_sweep_structure_is_accepted():
     assert check_collective_structure(GOOD_HLO, n_rounds=3) == []
     bodies, counts = collective_bodies(GOOD_HLO)
-    assert counts == {"all-gather": 1}
-    assert len(bodies) == 1
-    assert bodies[0].comp == "loop_body" and bodies[0].trip == 6
+    assert counts == {"all-gather": 2}
+    assert [(b.comp, b.trip) for b in bodies] == [("loop_body", 3),
+                                                  ("back_body", 3)]
 
 
 def test_extra_gather_per_round_is_pinned():
@@ -186,10 +196,12 @@ def test_forbidden_all_reduce_is_pinned():
 
 
 def test_wrong_trip_count_is_pinned():
-    text = GOOD_HLO.replace('"n":"6"', '"n":"4"')
+    text = GOOD_HLO.replace('"n":"3"', '"n":"4"', 1)
     vio = check_collective_structure(text, n_rounds=3)
     assert any(v.kind == "trip-count-mismatch" and v.round == 4
                and "2S = 6" in v.detail for v in vio), [str(v) for v in vio]
+    # two segments of 3 and 4 rounds are a different sweep
+    assert check_collective_structure(text, n_rounds=[3, 4]) != []
 
 
 def test_untiled_gather_is_pinned():

@@ -30,6 +30,13 @@ def plan():
     return build_plan(laplace_2d(16, 16), dtype=jnp.float64)
 
 
+@pytest.fixture(scope="module")
+def segmented_plan():
+    # its colors' rounds differ in width: three lane-width segments
+    return build_plan(laplace_2d(20, 20), block_size=8, w=4,
+                      dtype=jnp.float64)
+
+
 def _computations(hlo: str) -> dict:
     """computation name -> [(opcode, op_name or None, line)]."""
     comps, cur = {}, None
@@ -55,8 +62,12 @@ def _body(comps, instr_line):
     return comps[re.search(r"body=%([^,\s]+)", instr_line).group(1)]
 
 
+@pytest.mark.parametrize("which", ["plan", "segmented_plan"])
 @pytest.mark.parametrize("batched", [False, True])
-def test_every_op_of_the_compiled_pcg_loop_has_a_scope(plan, batched):
+def test_every_op_of_the_compiled_pcg_loop_has_a_scope(request, which,
+                                                       batched):
+    plan = request.getfixturevalue(which)
+    assert plan.n_segments == (1 if which == "plan" else 3)
     fn = plan._pcg_fn(batched, 1e-6, 50, False)
     b = jnp.zeros((plan.slab_m,) + ((2,) if batched else ()))
     hlo = fn.lower(plan._precond.tables, plan._spmv_vals, plan._spmv_cols,
@@ -75,12 +86,14 @@ def test_every_op_of_the_compiled_pcg_loop_has_a_scope(plan, batched):
         assert _scope(op_name) in PCG_SCOPES, line
         scopes.add(_scope(op_name))
     assert scopes == set(PCG_SCOPES)
-    # the sweep is a loop of its own inside the PCG loop, wholly a sweep
+    # the sweep is a forward and a backward loop per lane-width segment
+    # inside the PCG loop, each wholly a sweep
     sweeps = [ln for opc, op, ln in body
               if opc == "while" and _scope(op) == SWEEP_SCOPE]
-    assert len(sweeps) == 1
-    inner = [_scope(op) for _, op, _ in _body(comps, sweeps[0]) if op]
-    assert inner and set(inner) == {SWEEP_SCOPE}
+    assert len(sweeps) == 2 * plan.n_segments
+    for loop in sweeps:
+        inner = [_scope(op) for _, op, _ in _body(comps, loop) if op]
+        assert inner and set(inner) == {SWEEP_SCOPE}
 
 
 def test_the_slab_pcg_carries_the_same_scopes(plan):

@@ -73,23 +73,25 @@ def test_fused_matches_sequential_oracle(method, dtype, batched):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_fused_pallas_kernel_matches_oracle_bitwise(method, dtype):
     """The Pallas fused kernel agrees with its jnp oracle bit for bit, and
-    with the sequential oracle to dtype tolerance."""
+    with the sequential oracle to dtype tolerance.  The kernel takes
+    uniform tables: one segment, the halves stacked."""
     from repro.core.trisolve import DeviceFusedTables
     from repro.kernels.hbmc_trisolve import hbmc_trisolve_fused
     from repro.kernels.ref import hbmc_trisolve_fused_ref
     a, sysd, l_bar = _native_system(method)
     fwd_h, bwd_h = pack_factor(l_bar, sysd.fwd_rounds, sysd.bwd_rounds,
                                sysd.drop)
-    fused = fuse_round_major(fwd_h, bwd_h)
-    t = DeviceFusedTables.from_host(fused, dtype=dtype)
+    fused = fuse_round_major(fwd_h, bwd_h, max_segments=1)
+    cols, vals, dinv = DeviceFusedTables.from_host(fused,
+                                                   dtype=dtype).stacked()
     r = np.random.default_rng(1).normal(size=sysd.n_padded)
     if sysd.drop is not None:
         r[sysd.drop] = 0.0
     lay = fused.layout
-    q = jnp.asarray(lay.embed(r), dtype=dtype).reshape(lay.n_steps, lay.lanes)
-    z_k = np.asarray(hbmc_trisolve_fused(t.cols, t.vals, t.dinv, q,
+    q = jnp.asarray(lay.embed(r), dtype=dtype).reshape(lay.segments[0])
+    z_k = np.asarray(hbmc_trisolve_fused(cols, vals, dinv, q,
                                          interpret=True))
-    z_r = np.asarray(hbmc_trisolve_fused_ref(t.cols, t.vals, t.dinv, q))
+    z_r = np.asarray(hbmc_trisolve_fused_ref(cols, vals, dinv, q))
     np.testing.assert_array_equal(z_k, z_r)
     z = lay.extract(z_k).astype(np.float64)
     z_ref = sequential_ic_solve(l_bar, r)
@@ -204,11 +206,11 @@ def test_fused_layout_contract(method):
                                sysd.drop)
     fused = fuse_round_major(fwd_h, bwd_h)
     lay = fused.layout
-    s_, r_ = lay.n_steps, lay.lanes
-    assert fused.cols.shape[0] == 2 * s_
+    assert sum(h.cols.shape[0] for h in fused.fwd) == lay.n_steps
+    assert [h.dinv.shape for h in fused.bwd] == list(lay.segments)
     # every live unknown has exactly one round-major position, and
     # embed/extract invert each other on live unknowns
-    flat = lay.rows.reshape(-1)
+    flat = lay.rows
     live = flat != lay.n_slots - 1
     assert len(np.unique(flat[live])) == live.sum()
     v = np.random.default_rng(5).normal(size=lay.n_slots - 1)
@@ -217,14 +219,13 @@ def test_fused_layout_contract(method):
     np.testing.assert_array_equal(lay.extract(lay.embed(v)), v)
     # forward half gathers strictly below the destination slice, backward
     # half strictly above (triangular in execution order)
-    pos = np.arange(s_ * r_).reshape(s_, r_)
-    k = fused.cols.shape[-1]
-    dest = np.concatenate([pos, pos[::-1]])[:, :, None].repeat(k, axis=-1)
-    nz = fused.vals != 0.0
-    fwd_nz = nz[:s_]
-    bwd_nz = nz[s_:]
-    assert (fused.cols[:s_][fwd_nz] < dest[:s_][fwd_nz]).all()
-    assert (fused.cols[s_:][bwd_nz] > dest[s_:][bwd_nz]).all()
+    for f, b, off, (n, r) in zip(fused.fwd, fused.bwd, lay.offsets,
+                                 lay.segments):
+        dest = off + np.arange(n * r).reshape(n, 1, r)     # (n, K, R)
+        fwd_nz = f.vals != 0.0
+        bwd_nz = b.vals != 0.0
+        assert (f.cols < dest)[fwd_nz].all()
+        assert (b.cols > dest[::-1])[bwd_nz].all()
 
 
 def test_fuse_rejects_mismatched_rounds():
@@ -248,8 +249,10 @@ def test_compiled_pallas_plan_pads_rounds_and_refuses_f64():
     compiled = build_plan(a, backend="pallas", interpret=False,
                           dtype=jnp.float32, **knobs)
     interpreted = build_plan(a, backend="pallas", interpret=True, **knobs)
-    assert compiled._precond.tables.lanes % 1024 == 0
-    assert interpreted._precond.tables.lanes < 1024
+    assert compiled._precond.tables.segments[0][1] % 1024 == 0
+    assert interpreted._precond.tables.segments[0][1] < 1024
+    # the fused kernel takes uniform tables: Pallas plans keep one segment
+    assert compiled.n_segments == interpreted.n_segments == 1
     with pytest.raises(ValueError, match="float64"):
         build_plan(a, backend="pallas", interpret=False, **knobs)
     with pytest.raises(ValueError, match="float64"):

@@ -82,12 +82,63 @@ def test_sell_spmv_kernel_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_xla_fused_sweep_compiles(one_chip):
-    from repro.core.trisolve import DeviceFusedTables, fused_solve
-    cols, vals, dinv, q = _tables(one_chip, R_XLA)
-    compiled = fused_solve.lower(DeviceFusedTables(cols, vals, dinv),
-                                 q).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+# (rounds, lanes, forward K, backward K) of each lane-width segment: one
+# segment at the analogue's shapes, and the four of the heat2d benchmark
+# plan (525,625 rows, HBMC block 32, w 8), whose colors differ in width
+SEGMENTS = {"uniform": ((S_ROUNDS, R_XLA, K_TRI, K_TRI),),
+            "segmented": ((32, 7991, 2, 4), (32, 7909, 4, 3),
+                          (32, 434, 4, 3), (32, 99, 4, 1))}
+
+
+def _sweep_tables(sharding, segs):
+    from repro.core.trisolve import DeviceFusedTables, DeviceSweep
+
+    def half(n, r, k):
+        return DeviceSweep(_spec(sharding, (n, k, r), jnp.int32),
+                           _spec(sharding, (n, k, r), jnp.float32),
+                           _spec(sharding, (n, r), jnp.float32))
+
+    return (DeviceFusedTables(
+        fwd=tuple(half(n, r, kf) for n, r, kf, _ in segs),
+        bwd=tuple(half(n, r, kb) for n, r, _, kb in segs)),
+        sum(n * r for n, r, _, _ in segs))
+
+
+@pytest.mark.parametrize("shape", sorted(SEGMENTS))
+def test_xla_fused_sweep_compiles(one_chip, shape):
+    from repro.core.trisolve import fused_solve
+    segs = SEGMENTS[shape]
+    tables, m = _sweep_tables(one_chip, segs)
+    compiled = fused_solve.lower(
+        tables, _spec(one_chip, (m,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    # one loop per segment and half
+    assert text.count(" while(") == 2 * len(segs)
+
+
+def test_pcg_loop_keeps_the_sweep_tables_lane_dense(one_chip):
+    """Carried through the PCG loop, a sweep table may not be re-laid with
+    its K (1 to 4) on the 128-lane axis.  At heat2d's segments, tables
+    packed ``(n, R, K)`` compiled to 816 MB of temporary buffers that way;
+    packed ``(n, K, R)`` the whole solve's temporaries stay below the 68
+    MB its tables and ELL operand take."""
+    from repro.core.iccg import _pcg_device
+    from repro.core.plan import _make_spmv
+    from repro.core.trisolve import RoundMajorPreconditioner
+    tables, m = _sweep_tables(one_chip, SEGMENTS["segmented"])
+
+    def solve(tables, vals, cols, b):
+        spmv = _make_spmv("ell", m, vals, cols, False)
+        return _pcg_device(spmv, RoundMajorPreconditioner(tables), b,
+                           rtol=1e-6, maxiter=1000)
+
+    compiled = jax.jit(solve).lower(
+        tables, _spec(one_chip, (5, m), jnp.float32),
+        _spec(one_chip, (5, m), jnp.int32),
+        _spec(one_chip, (m,), jnp.float32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < mem.argument_size_in_bytes, mem
 
 
 def test_xla_ell_spmv_is_lane_dense(one_chip):
