@@ -76,6 +76,10 @@ class ICCGReport:
     embed_seconds: float = 0.0
     extract_seconds: float = 0.0
     n_segments: int = 1     # lane-width segments of the round-major sweep
+    # the sweep's work an apply, counted at plan build (see SolverPlan)
+    sweep_steps: int = 0
+    sweep_gather_slots: int = 0
+    sweep_live_entries: int = 0
 
 
 @dataclasses.dataclass
@@ -97,6 +101,9 @@ class BatchedICCGReport:
     embed_seconds: float = 0.0
     extract_seconds: float = 0.0
     n_segments: int = 1
+    sweep_steps: int = 0
+    sweep_gather_slots: int = 0
+    sweep_live_entries: int = 0
 
 
 @dataclasses.dataclass
@@ -302,6 +309,15 @@ _MAX_ESCALATIONS = 16
 ON_BREAKDOWN = ("clamp", "raise", "escalate")
 
 
+def _live_entries(l_bar: sp.csr_matrix, drop) -> int:
+    """The factor's off-diagonal entries between kept rows, once in each
+    sweep half: the gather slots that point at a real position."""
+    low = sp.tril(l_bar, k=-1, format="coo")
+    if drop is None:
+        return 2 * low.nnz
+    return 2 * int(np.count_nonzero(~(drop[low.row] | drop[low.col])))
+
+
 def _occupancy_from_rounds(rounds, drop) -> float:
     if drop is not None:
         rounds = [r[~drop[r]] for r in rounds]
@@ -318,6 +334,12 @@ class SolverPlan:
     plan caches the ordering, rounds, IC(0) structure, fused round-major
     tables, packed SpMV operand and jitted PCG; ``solve``/``solve_batched``
     reuse all of it, ``refactor`` renews only the numeric parts.
+
+    Counters of the sweep's work an apply, fixed at build: ``sweep_steps``
+    (sequential steps, ``2 * n_rounds``), ``sweep_gather_slots`` (values
+    gathered, rounds x K x lanes over every segment and half) and
+    ``sweep_live_entries`` (the slots that point at a real position: the
+    factor's off-diagonal entries, once per half).
 
     ``setup_count`` counts host-side setup passes (initial build and every
     ``refactor``); it must NOT change across ``solve`` calls — asserted by
@@ -477,6 +499,18 @@ class SolverPlan:
         layout, whose rounds share one width)."""
         return self._rm.n_segments if self._rm is not None else 1
 
+    @property
+    def sweep_steps(self) -> int:
+        """Sequential steps of one preconditioner apply (both halves)."""
+        return 2 * self.n_rounds
+
+    def _counters(self) -> dict:
+        """The layout's counters, as the reports carry them."""
+        return dict(lane_occupancy=self.lane_occupancy,
+                    n_segments=self.n_segments, sweep_steps=self.sweep_steps,
+                    sweep_gather_slots=self.sweep_gather_slots,
+                    sweep_live_entries=self.sweep_live_entries)
+
     # -- setup internals ----------------------------------------------------
 
     @property
@@ -501,6 +535,8 @@ class SolverPlan:
         self._precond, self._rm = _build_preconditioner(
             l_bar, self._sysd, self.dtype, self.backend, self.interpret,
             self.layout, self.lane_multiple)
+        self.sweep_gather_slots = self._precond.gather_slots
+        self.sweep_live_entries = _live_entries(l_bar, self._sysd.drop)
         a_op = (sell.permute_round_major(self._sysd.a_bar, self._rm)
                 if self._rm is not None else self._sysd.a_bar)
         self._spmv_vals, self._spmv_cols, self._spmv_n = _pack_spmv(
@@ -955,11 +991,10 @@ class SolverPlan:
             method=self.method, result=res, n=self.n,
             n_padded=self.n_padded, n_colors=self.n_colors,
             n_rounds=self.n_rounds, setup_seconds=t[SOLVE_EMBED],
-            solve_seconds=t[SOLVE_PCG], lane_occupancy=self.lane_occupancy,
-            x=x_out, backend=self.backend, layout=self.layout,
-            spmv_backend=self.spmv_backend, scheduler=self.scheduler,
-            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT],
-            n_segments=self.n_segments)
+            solve_seconds=t[SOLVE_PCG], x=x_out, backend=self.backend,
+            layout=self.layout, spmv_backend=self.spmv_backend,
+            scheduler=self.scheduler, embed_seconds=t[SOLVE_EMBED],
+            extract_seconds=t[SOLVE_EXTRACT], **self._counters())
 
     def solve(self, b: np.ndarray, rtol: float = 1e-7,
               maxiter: int = 10_000,
@@ -993,11 +1028,10 @@ class SolverPlan:
             method=self.method, result=res, n=self.n,
             n_padded=self.n_padded, n_colors=self.n_colors,
             n_rounds=self.n_rounds, setup_seconds=t[SOLVE_EMBED],
-            solve_seconds=t[SOLVE_PCG], lane_occupancy=self.lane_occupancy,
-            x=x_out, backend=self.backend, layout=self.layout,
-            spmv_backend=self.spmv_backend, scheduler=self.scheduler,
-            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT],
-            n_segments=self.n_segments)
+            solve_seconds=t[SOLVE_PCG], x=x_out, backend=self.backend,
+            layout=self.layout, spmv_backend=self.spmv_backend,
+            scheduler=self.scheduler, embed_seconds=t[SOLVE_EMBED],
+            extract_seconds=t[SOLVE_EXTRACT], **self._counters())
 
     def solve_batched(self, b: np.ndarray, rtol: float = 1e-7,
                       maxiter: int = 10_000,
@@ -1027,11 +1061,10 @@ class SolverPlan:
             method=self.method, result=res, n=self.n,
             n_padded=self.n_padded, n_colors=self.n_colors,
             n_rounds=self.n_rounds, setup_seconds=t[SOLVE_EMBED],
-            solve_seconds=t[SOLVE_PCG], lane_occupancy=self.lane_occupancy,
-            x=x_out, backend=self.backend, layout=self.layout,
-            spmv_backend=self.spmv_backend, scheduler=self.scheduler,
-            embed_seconds=t[SOLVE_EMBED], extract_seconds=t[SOLVE_EXTRACT],
-            n_segments=self.n_segments)
+            solve_seconds=t[SOLVE_PCG], x=x_out, backend=self.backend,
+            layout=self.layout, spmv_backend=self.spmv_backend,
+            scheduler=self.scheduler, embed_seconds=t[SOLVE_EMBED],
+            extract_seconds=t[SOLVE_EXTRACT], **self._counters())
 
 
 def build_plan(a: sp.spmatrix, method: str = "hbmc", block_size: int = 32,

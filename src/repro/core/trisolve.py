@@ -211,6 +211,12 @@ class DeviceFusedTables:
         """Length of the round-major state."""
         return sum(n * r for n, r in self.segments)
 
+    @property
+    def gather_slots(self) -> int:
+        """Values an apply gathers: rounds x K x lanes, over every segment
+        and half."""
+        return sum(t.cols.size for t in self.fwd + self.bwd)
+
     def stacked(self) -> tuple[jax.Array, jax.Array, jax.Array]:
         """The single segment as the Pallas fused kernel's ``(2S, R, K)``
         operands (``sell.stack_sweeps``)."""
@@ -430,6 +436,10 @@ class RoundMajorPreconditioner:
     def m(self) -> int:
         return self.tables.m
 
+    @property
+    def gather_slots(self) -> int:
+        return self.tables.gather_slots
+
     def _pallas_operands(self, r: jax.Array, batched: bool):
         cols, vals, dinv = self.tables.stacked()
         s_, lanes = self.tables.segments[0]
@@ -514,6 +524,13 @@ class HBMCPreconditioner:
     def n_rounds(self) -> int:
         t = self.fwd if self.fwd is not None else self.kernel.fwd
         return int(t.rows.shape[0])
+
+    @property
+    def gather_slots(self) -> int:
+        """Values an apply gathers: both halves' (S, R, K) tables."""
+        tabs = (self.fwd, self.bwd) if self.fwd is not None else (
+            self.kernel.fwd, self.kernel.bwd)
+        return sum(t.cols.size for t in tabs)
 
     def __call__(self, r: jax.Array) -> jax.Array:
         if self.backend == "pallas":

@@ -153,3 +153,59 @@ def test_xla_ell_spmv_is_lane_dense(one_chip):
         _spec(one_chip, (n,), jnp.float32)).compile()
     padded = n * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < padded // 8
+
+
+
+@pytest.fixture(scope="module")
+def hpcg3d_plan():
+    """The hpcg3d benchmark plan: HPCG's 27-point operator on a 48^3 grid
+    (bench/configs/hpcg3d.py), eight segments of 502 lanes down to 1, K
+    up to 26 on the sublane axis of every sweep table."""
+    import importlib.util
+    from pathlib import Path
+
+    from repro.core import build_plan
+    path = Path(__file__).resolve().parents[1] / "bench/configs/hpcg3d.py"
+    spec = importlib.util.spec_from_file_location("hpcg3d_generator", path)
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    return build_plan(gen.hpcg_27pt(48), method="hbmc", block_size=32, w=8)
+
+
+@pytest.mark.parametrize("what, dtype", [("pcg", jnp.float32),
+                                         ("sweep", jnp.float64)])
+def test_hpcg3d_shapes_keep_scratch_below_the_arguments(
+        one_chip, hpcg3d_plan, what, dtype):
+    """At K = 26 the sweep tables stay lane-dense inside the PCG loop: the
+    whole PCG at hpcg3d's shapes keeps its scratch below its arguments.
+    In float64 that holds for the sweep; the float64 PCG's scratch is
+    over its arguments at every size, poisson2d's too (24 MB against 14
+    MB), because the chip's float64 emulation of the ELL SpMV's
+    contraction holds 8 float32 planes of the (K, m) operand (504 MB
+    against 113 MB here)."""
+    from repro.core.iccg import _pcg_device
+    from repro.core.plan import _make_spmv
+    from repro.core.trisolve import RoundMajorPreconditioner, fused_solve
+
+    plan = hpcg3d_plan
+    host = plan._precond.tables
+    assert max(t.cols.shape[1] for t in host.fwd + host.bwd) == 26
+    assert [r for _, r in host.segments][-2:] == [23, 1]
+    tables = jax.tree.map(lambda x: _spec(one_chip, x.shape, (
+        dtype if jnp.issubdtype(x.dtype, jnp.floating) else x.dtype)), host)
+    m, k_ell = host.m, plan._spmv_cols.shape[0]
+    assert k_ell == 27
+    q = _spec(one_chip, (m,), dtype)
+    if what == "sweep":
+        compiled = fused_solve.lower(tables, q).compile()
+    else:
+        def solve(tables, vals, cols, b):
+            spmv = _make_spmv("ell", m, vals, cols, False)
+            return _pcg_device(spmv, RoundMajorPreconditioner(tables), b,
+                               rtol=1e-7, maxiter=2000)
+
+        compiled = jax.jit(solve).lower(
+            tables, _spec(one_chip, (k_ell, m), dtype),
+            _spec(one_chip, (k_ell, m), jnp.int32), q).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < mem.argument_size_in_bytes, mem
