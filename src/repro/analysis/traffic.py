@@ -112,26 +112,35 @@ def measured_slice_bytes(text: str) -> float:
     return cost(entry)
 
 
-def _apply_static_bytes(plan) -> tuple[float, str]:
-    """Sliced bytes of one round-major apply, from the table shapes.
+def _apply_static_bytes(plan) -> tuple[float, float, str]:
+    """(sliced, whole, detail) bytes of one round-major apply, from the
+    table shapes.
 
     Per step of a segment half the sweep slices: cols (R*K int32) + vals
     (R*K item) + dinv (R item) + the right-hand-side read (q forward, y
     backward), the y gather (R*K item) and the y update write — exactly
     the slice-family ops the optimized HLO exposes, so static == measured
-    when nothing leaks.
+    when nothing leaks.  At K = 1 the gather and its fill mask slice the
+    cols apart, twice a step.  A segment of one round is a loop of one
+    trip, which XLA on the CPU inlines: its step reads its tables whole,
+    with no slice, so they are the static-only ``whole`` bytes.
     """
     t = plan._precond.tables
     item = plan._np_dtype.itemsize
-    total = 0.0
+    sliced = whole = 0.0
     for h in t.fwd + t.bwd:
         n, k, r = h.cols.shape
-        total += n * (r * k * (h.cols.dtype.itemsize + 2 * item)
-                      + 3 * r * item)
-    return total, (f"2S={2 * t.n_steps} steps in {2 * t.n_segments} loops "
-                   f"(K, R per half: "
-                   f"{[h.cols.shape[1:] for h in t.fwd + t.bwd]}, "
-                   f"{item}B items)")
+        cols = r * k * h.cols.dtype.itemsize
+        tables = cols + r * k * item + r * item
+        state = r * k * item + 2 * r * item
+        if n == 1:
+            sliced, whole = sliced + state, whole + tables
+        else:
+            sliced += n * (tables + state + (cols if k == 1 else 0))
+    return sliced, whole, (f"2S={2 * t.n_steps} steps in {2 * t.n_segments}"
+                           f" halves (K, R per half: "
+                           f"{[h.cols.shape[1:] for h in t.fwd + t.bwd]}, "
+                           f"{item}B items)")
 
 
 def _spmv_gather_bytes(plan) -> tuple[float, str]:
@@ -168,7 +177,7 @@ def traffic_report(plan, measure: bool = True) -> TrafficReport:
     table_slots = sum(h.cols.size for h in t.fwd + t.bwd)
     slots = int(np.prod(plan._spmv_vals.shape))
 
-    apply_static, apply_detail = _apply_static_bytes(plan)
+    apply_static, apply_whole, apply_detail = _apply_static_bytes(plan)
     gather_static, gather_detail = _spmv_gather_bytes(plan)
     apply_measured = gather_measured = None
     measurable = (measure and plan.mesh is None
@@ -196,6 +205,8 @@ def traffic_report(plan, measure: bool = True) -> TrafficReport:
     vector_stream = float(VECTOR_STREAMS_PER_ITERATION * m * item)
     terms = (
         TrafficTerm("apply", apply_static, apply_measured, apply_detail),
+        TrafficTerm("apply/tables", apply_whole, None,
+                    "tables of one-round segments, read whole"),
         TrafficTerm("spmv/gather", gather_static, gather_measured,
                     gather_detail),
         TrafficTerm("spmv/stream", spmv_stream, None,

@@ -368,8 +368,8 @@ def to_round_major(t: StepTables) -> RoundMajorTables:
 
 #: most segments a sweep is cut into (each runs two program loops an apply)
 MAX_SEGMENTS = 16
-#: padding a segment may gather, as a share of what its runs of equal-width
-#: rounds gather when each is packed alone
+#: padding a segment may gather, as a share of what its runs of rounds of
+#: one width and one K per half gather when each is packed alone
 SEGMENT_SLACK = 1 / 8
 
 
@@ -381,23 +381,24 @@ def segment_bounds(live, k_fwd, k_bwd, lane_multiple: int = 1,
     A segment packs its rounds at one lane width (its widest round, rounded
     up to ``lane_multiple``) and one K per sweep half (its widest forward
     row and its widest backward row), so an apply gathers ``rounds x width
-    x (K_fwd + K_bwd)`` values for it.  Consecutive rounds of one width
-    form a run, and cuts fall only between runs: rounds that all have one
-    width make one segment.  In forward order, a run joins the open
-    segment if the joined segment gathers at most ``SEGMENT_SLACK`` more
-    than its runs gather packed each alone; otherwise it opens a new
-    segment.  While that gives more than ``max_segments`` segments, the
-    slack doubles.  ``live`` is the rounds' live lane counts, ``k_fwd`` /
-    ``k_bwd`` their widest live rows in each half (forward round order).
+    x (K_fwd + K_bwd)`` values for it.  Consecutive rounds that share one
+    width and one K per half (K at least 1) form a run, and cuts fall only
+    between runs: rounds that share one width and one K per half make one
+    segment.  In forward order, a run joins the open segment if the
+    joined segment gathers at most ``SEGMENT_SLACK`` more than its runs
+    gather packed each alone; otherwise it opens a new segment.  While
+    that gives more than ``max_segments`` segments, the slack doubles.
+    ``live`` is the rounds' live lane counts, ``k_fwd`` / ``k_bwd`` their
+    widest live rows in each half (forward round order).
     """
     lm = max(int(lane_multiple), 1)
     width = -(-np.asarray(live, dtype=np.int64) // lm) * lm
     kf = np.maximum(np.asarray(k_fwd, dtype=np.int64), 1)
     kb = np.maximum(np.asarray(k_bwd, dtype=np.int64), 1)
     s_ = len(width)
-    starts = np.concatenate([[0], np.flatnonzero(np.diff(width)) + 1, [s_]])
-    runs = [(int(a), b - a, int(width[a]), int(kf[a:b].max()),
-             int(kb[a:b].max()))
+    parts = np.any(np.diff(np.stack([width, kf, kb])), axis=0)
+    starts = np.concatenate([[0], np.flatnonzero(parts) + 1, [s_]])
+    runs = [(int(a), b - a, int(width[a]), int(kf[a]), int(kb[a]))
             for a, b in zip(starts[:-1], starts[1:])]
     slack = SEGMENT_SLACK
     while True:

@@ -8,9 +8,9 @@ runs the same preconditioned iteration in another order of arithmetic,
 so iteration counts agree to one and solutions to rounding.
 
 The matrix is the benchmark's own generator of HPCG's operator
-(``bench/configs/hpcg3d.py``).  At m = 20 the plan is cut into four
-lane-width segments with 9- and 5-lane tails and K = 26, the shape of
-the hpcg3d cell's plan at a size the CPU solves in seconds.
+(``bench/configs/hpcg3d.py``).  At m = 20 the plan is cut into ten
+segments with 9- and 5-lane tails and K = 26, the shape of the hpcg3d
+cell's plan at a size the CPU solves in seconds.
 """
 import importlib.util
 from pathlib import Path
@@ -97,8 +97,8 @@ def reference_solve(a, plan, b):
     return x, it
 
 
-@pytest.mark.parametrize("m, colours, segments", [(12, 5, 4), (16, 4, 1),
-                                                  (20, 6, 4)])
+@pytest.mark.parametrize("m, colours, segments", [(12, 5, 6), (16, 4, 6),
+                                                  (20, 6, 10)])
 def test_plan_matches_the_plain_reference(m, colours, segments):
     a = hpcg_27pt(m)
     plan = build_plan(a, method="hbmc", block_size=32, w=8)
@@ -123,11 +123,12 @@ def test_hpcg_rhs_solves_to_ones():
 
 
 def test_multi_segment_plan_has_the_cells_shape():
-    """m = 20: 9- and 5-lane tail segments and K = 26 on both halves."""
+    """m = 20: 9- and 5-lane tail segments, each tail cut once more where
+    K parts, and K = 26 on both halves."""
     plan = build_plan(hpcg_27pt(20), method="hbmc", block_size=32, w=8)
     tables = plan._precond.tables
     lanes = [r for _, r in tables.segments]
-    assert lanes[-2:] == [9, 5]
+    assert lanes[-4:] == [9, 9, 5, 5]
     ks = {t.cols.shape[1] for t in tables.fwd + tables.bwd}
     assert max(ks) == 26
 

@@ -186,6 +186,61 @@ def test_extra_gather_per_round_is_pinned():
     assert "loop_body" in extra[0].detail and "ag2" in extra[0].detail
 
 
+# a one-round segment's two steps, inlined into the entry (XLA removes a
+# loop of one trip), one tiled all-gather each
+INLINE_STEPS = (
+    "  %s1 = f64[2]{0} slice(%p1), slice={[0:2]}\n"
+    "  %i1 = f64[8]{0} all-gather(%s1), replica_groups={{0,1,2,3}}, "
+    "dimensions={0}\n"
+    "  %i2 = f64[8]{0} all-gather(%s1), replica_groups={{0,1,2,3}}, "
+    "dimensions={0}\n")
+
+
+@pytest.mark.parametrize("inline, n_rounds, ok", [
+    (True, [3, 1], True),       # the one-round segment's steps, inline
+    (False, [3, 1], False),     # ... missing
+    (True, 3, False),           # inline gathers of no segment
+    (True, [3, 1, 1], False),   # one one-round segment short
+    ("loops", [3, 1], True),    # ... or its steps as loops of one trip
+])
+def test_one_round_segments_gather_inline(inline, n_rounds, ok):
+    text = (GOOD_HLO.replace("  ROOT %out =", INLINE_STEPS + "  ROOT %out =")
+            if inline is True else GOOD_HLO)
+    if inline == "loops":
+        # the second segment's two loops of one trip, over the same bodies
+        text = GOOD_HLO + GOOD_HLO[GOOD_HLO.index("%cond"):
+                                   GOOD_HLO.index("ENTRY")].replace(
+            "%cond", "%cond1").replace("_body", "_body1").replace(
+            "%ag", "%ag1")
+        text = text.replace(
+            "  ROOT %out = f64[8]{0} get-tuple-element(%w2), index=0",
+            '  %w3 = (f64[8]{0}) while(%w2), condition=%cond1, '
+            'body=%loop_body1, backend_config={"known_trip_count":'
+            '{"n":"1"}}\n'
+            '  %w4 = (f64[8]{0}) while(%w3), condition=%cond1, '
+            'body=%back_body1, backend_config={"known_trip_count":'
+            '{"n":"1"}}\n'
+            "  ROOT %out = f64[8]{0} get-tuple-element(%w4), index=0")
+        assert text.count(" while(") == 4
+    vio = check_collective_structure(text, n_rounds=n_rounds)
+    assert (vio == []) == ok, [str(v) for v in vio]
+
+
+def test_only_the_pcg_body_holds_the_inlined_steps():
+    """``inline_gathers`` lets exactly one while body hold that many
+    gathers beside its own one; every other body still holds one."""
+    one = GOOD_HLO.replace("  ROOT %r =", EXTRA_GATHER_LINE + "  ROOT %r =")
+    assert check_collective_structure(one, inline_gathers=1) == []
+    assert check_collective_structure(one, inline_gathers=2) != []
+    assert check_collective_structure(one) != []
+    second = EXTRA_GATHER_LINE.replace("%ag2", "%agb2").replace("%src",
+                                                                "%src2")
+    both = one.replace("  ROOT %r2 =", second + "  ROOT %r2 =")
+    vio = check_collective_structure(both, inline_gathers=1)
+    assert [v.kind for v in vio] == ["extra-collective"], \
+        [str(v) for v in vio]
+
+
 def test_forbidden_all_reduce_is_pinned():
     text = GOOD_HLO.replace("all-gather", "all-reduce")
     vio = check_collective_structure(text, n_rounds=3)

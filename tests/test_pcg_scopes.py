@@ -27,12 +27,14 @@ COMPILER_MADE = {"parameter", "get-tuple-element", "tuple", "constant",
 
 @pytest.fixture(scope="module")
 def plan():
+    # one width, cut where K parts: three segments
     return build_plan(laplace_2d(16, 16), dtype=jnp.float64)
 
 
 @pytest.fixture(scope="module")
 def segmented_plan():
-    # its colors' rounds differ in width: three lane-width segments
+    # its colors' rounds differ in width, and K parts inside each color:
+    # eleven segments, five of them one round long
     return build_plan(laplace_2d(20, 20), block_size=8, w=4,
                       dtype=jnp.float64)
 
@@ -67,7 +69,7 @@ def _body(comps, instr_line):
 def test_every_op_of_the_compiled_pcg_loop_has_a_scope(request, which,
                                                        batched):
     plan = request.getfixturevalue(which)
-    assert plan.n_segments == (1 if which == "plan" else 3)
+    assert plan.n_segments == (3 if which == "plan" else 11)
     fn = plan._pcg_fn(batched, 1e-6, 50, False)
     b = jnp.zeros((plan.slab_m,) + ((2,) if batched else ()))
     hlo = fn.lower(plan._precond.tables, plan._spmv_vals, plan._spmv_cols,
@@ -86,11 +88,13 @@ def test_every_op_of_the_compiled_pcg_loop_has_a_scope(request, which,
         assert _scope(op_name) in PCG_SCOPES, line
         scopes.add(_scope(op_name))
     assert scopes == set(PCG_SCOPES)
-    # the sweep is a forward and a backward loop per lane-width segment
-    # inside the PCG loop, each wholly a sweep
+    # the sweep is a forward and a backward loop per segment inside the
+    # PCG loop, each wholly a sweep; XLA inlines a loop of one trip, so a
+    # one-round segment's steps run in the PCG loop's body
     sweeps = [ln for opc, op, ln in body
               if opc == "while" and _scope(op) == SWEEP_SCOPE]
-    assert len(sweeps) == 2 * plan.n_segments
+    assert len(sweeps) == 2 * sum(n > 1 for n, _ in
+                                  plan._precond.tables.segments)
     for loop in sweeps:
         inner = [_scope(op) for _, op, _ in _body(comps, loop) if op]
         assert inner and set(inner) == {SWEEP_SCOPE}

@@ -1,22 +1,26 @@
-"""The segmented round-major layout: each run of rounds at its own width.
+"""The segmented round-major layout: each run of rounds at its own width
+and K.
 
 HBMC's colors can differ widely in width (the heat-step benchmark's four
-colors hold 7,991, about 7,900, 434 and 99 lanes a round).  The sweep
-packs consecutive rounds into segments, each at its own lane width and
-its own K per half (``sell.segment_bounds``), so padding lanes are
-neither gathered by the sweep nor carried by the SpMV and vector work.
-Pinned here:
+colors hold 7,991, about 7,900, 434 and 99 lanes a round), and rounds of
+one width can differ in K (the Poisson benchmark's first color gathers
+one forward entry a row and three backward, its second three and one).
+The sweep packs consecutive rounds into segments, each at its own lane
+width and its own K per half (``sell.segment_bounds``), so padding lanes
+and padding slots are neither gathered by the sweep nor carried by the
+SpMV and vector work.  Pinned here:
 
   1. segment widths and K are the live maxima of their rounds, and the
-     cut falls where the colors' widths part;
+     cuts fall where width or K parts;
   2. the segmented apply (single and batched) is the sequential
-     substitution, and a plan of one width is one segment bitwise equal
-     to the single-width packing;
+     substitution, and one segment (rounds of one width and one K per
+     half, or ``max_segments=1``) is bitwise the single-width packing;
   3. the flat state round-trips through embed/extract, and the plan and
      its reports count lane occupancy over the layout's slots;
   4. the mesh plan stays bitwise the single-device plan with the same
      lane multiple, one tiled all-gather per step of every segment.
 """
+import importlib.util
 import os
 import subprocess
 import sys
@@ -30,6 +34,7 @@ import scipy.sparse as sp
 from repro.analysis import check_fused_tables
 from repro.core import (build_plan, fuse_round_major, ic0, pack_factor,
                         round_major_layout, segment_bounds)
+from repro.core.sell import MAX_SEGMENTS
 from repro.core.matrices import laplace_2d
 from repro.core.plan import _order_system
 from repro.core.sell import stack_sweeps
@@ -38,6 +43,18 @@ from repro.core.trisolve import (build_round_major_preconditioner_from_rounds,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BS, W = 8, 4
+# the 20 x 20 grid's plan: its colors' rounds hold 21 to 20, 7 to 6 and 3
+# lanes, and K changes inside each color
+SEGMENTS_20 = ((6, 21), (3, 20), (3, 20), (3, 20), (1, 20), (1, 7), (4, 7),
+               (2, 6), (1, 6), (7, 3), (1, 3))
+
+
+def _load(name, relpath):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(REPO, relpath))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _system(nx, ny, method="hbmc"):
@@ -57,51 +74,99 @@ def _fused(sysd, l_bar, **kw):
 # ---------------------------------------------------------------------------
 
 def test_unequal_colors_get_their_own_segments():
-    """On a 20 x 20 grid the colors' rounds hold 21, 7 and 3 lanes: one
-    segment each, at the colors' own widths and K."""
+    """On a 20 x 20 grid the colors' rounds hold 21 to 20, 7 to 6 and 3
+    lanes: the cuts fall at both color boundaries and where K parts inside
+    a color, each segment at its rounds' own widest lane count and K."""
     sysd, l_bar = _system(20, 20)
     fwd, bwd, fused = _fused(sysd, l_bar)
     lay = fused.layout
-    assert lay.segments == ((16, 21), (8, 7), (8, 3))
-    kf, kb = fwd.live_k, bwd.live_k[::-1]
-    start = 0
+    assert lay.segments == SEGMENTS_20
+    kf, kb = np.maximum(fwd.live_k, 1), np.maximum(bwd.live_k[::-1], 1)
+    key = np.stack([fwd.live, kf, kb], axis=1)
+    start, starts = 0, []
     for f, b, (n, r) in zip(fused.fwd, fused.bwd, lay.segments):
         rounds = slice(start, start + n)
-        assert start % BS == 0                  # a color boundary
+        if start:                               # width or K parts here
+            assert (key[start] != key[start - 1]).any()
         assert r == fwd.live[rounds].max()
         assert f.cols.shape == (n, kf[rounds].max(), r)     # lanes minor
         assert b.cols.shape == (n, kb[rounds].max(), r)
+        starts.append(start)
         start += n
     assert start == lay.n_steps == fwd.rows.shape[0]
-    assert [f.cols.shape[1] for f in fused.fwd] == [4, 4, 4]
-    assert [b.cols.shape[1] for b in fused.bwd] == [4, 3, 1]
+    assert {2 * BS, 3 * BS} <= set(starts)      # the color boundaries
+    assert [f.cols.shape[1] for f in fused.fwd] == [1, 2, 4, 3, 4, 2, 4, 3,
+                                                    4, 3, 4]
+    assert [b.cols.shape[1] for b in fused.bwd] == [4, 4, 2, 3, 2, 3, 2, 2,
+                                                    1, 1, 1]
+
+
+def _pattern_tables(a):
+    """Forward and backward StepTables of a benchmark pattern (HBMC block
+    32, w 8); the factor's values do not move the counts."""
+    sysd = _order_system(sp.csr_matrix(a), None, "hbmc", 32, 8)
+    return pack_factor(sp.tril(sysd.a_bar, format="csr"), sysd.fwd_rounds,
+                       sysd.bwd_rounds, sysd.drop)
+
+
+def _gathers(fused):
+    """(slots, live slots) an apply gathers: missing positions read m."""
+    halves = fused.fwd + fused.bwd
+    return (sum(h.cols.size for h in halves),
+            sum(int(np.count_nonzero(h.cols != fused.layout.m))
+                for h in halves))
 
 
 def test_heat_step_pattern_counts():
-    """The heat2d benchmark's pattern (725 x 725, HBMC block 32, w 8):
-    four segments, one per color, and 3,418,944 gathered values an apply
-    where one width gathered 8,182,784."""
-    sysd = _order_system(sp.csr_matrix(laplace_2d(725, 725)), None, "hbmc",
-                         32, 8)
-    fwd, bwd = pack_factor(sp.tril(sysd.a_bar, format="csr"),
-                           sysd.fwd_rounds, sysd.bwd_rounds, sysd.drop)
+    """The heat2d benchmark's pattern (725 x 725, HBMC block 32, w 8): six
+    segments, cut at the colors and where K parts inside the first and
+    last, and 3,153,110 gathered values an apply where one width gathered
+    8,182,784.  Rounds 32 and 33 (7,909 and 7,908 lanes) ride with round
+    31 at 7,991."""
+    fwd, bwd = _pattern_tables(laplace_2d(725, 725))
     fused = fuse_round_major(fwd, bwd)
     lay = fused.layout
-    assert lay.segments == ((32, 7991), (32, 7909), (32, 434), (32, 99))
+    assert lay.segments == ((31, 7991), (3, 7991), (30, 7908), (32, 434),
+                            (31, 99), (1, 99))
     assert [(f.cols.shape[1], b.cols.shape[1])
-            for f, b in zip(fused.fwd, fused.bwd)] == [(2, 4), (4, 3),
-                                                       (4, 3), (4, 1)]
-    assert lay.m == 525_856
-    assert lay.lane_occupancy == pytest.approx(525_625 / 525_856)
-    assert sum(h.cols.size for h in fused.fwd + fused.bwd) == 3_418_944
+            for f, b in zip(fused.fwd, fused.bwd)] == [(1, 4), (3, 3),
+                                                       (4, 3), (4, 3),
+                                                       (3, 1), (4, 1)]
+    assert lay.m == 525_990
+    assert lay.lane_occupancy == pytest.approx(525_625 / 525_990)
+    assert _gathers(fused) == (3_153_110, 2_099_600)
     single = fuse_round_major(fwd, bwd, max_segments=1)
     assert single.layout.segments == ((128, 7991),)
     assert sum(h.cols.size for h in single.fwd + single.bwd) == 8_182_784
 
 
+@pytest.mark.parametrize("which, bounds, gathers", [
+    # gallery('poisson', 256): 64 rounds of 1,024 lanes, cut where K parts
+    ("poisson2d", [0, 1, 32, 63, 64], (264_192, 261_120)),
+    # HPCG's 27-point operator on 48^3: 14 segments, 768 steps an apply
+    ("hpcg3d", [0, 32, 60, 97, 127, 128, 192, 223, 225, 256, 289, 350, 352,
+                353, 384], (4_633_334, 2_752_696)),
+])
+def test_benchmark_pattern_counts(which, bounds, gathers):
+    """The other benchmark plans' cuts and their gathers an apply (slots,
+    live); the steps an apply stay 2 x rounds whatever the cut."""
+    if which == "poisson2d":
+        a = _load("bench_matrices", "bench/matrices.py").poisson_2d(256)
+    else:
+        a = _load("hpcg3d_generator",
+                  "bench/configs/hpcg3d.py").hpcg_27pt(48)
+    fwd, bwd = _pattern_tables(a)
+    fused = fuse_round_major(fwd, bwd)
+    assert list(np.cumsum([0] + [n for n, _ in fused.layout.segments])) \
+        == bounds
+    assert _gathers(fused) == gathers
+    assert fused.n_steps == fwd.rows.shape[0] == bounds[-1]
+
+
 @pytest.mark.parametrize("widths,kf,kb,lm,want", [
-    # one width: one segment, whatever K does
-    ([8] * 6, [0, 1, 1, 3, 3, 3], [4, 4, 4, 1, 1, 1], 1, [0, 6]),
+    # one width, but K parts: a cut where the joined segment would gather
+    # more than the slack allows
+    ([8] * 6, [0, 1, 1, 3, 3, 3], [4, 4, 4, 1, 1, 1], 1, [0, 3, 6]),
     # a narrow color after a wide one opens a segment
     ([64] * 4 + [8] * 4, [2] * 8, [2] * 8, 1, [0, 4, 8]),
     # a width that differs by a lane or two rides along
@@ -110,6 +175,10 @@ def test_heat_step_pattern_counts():
     ([64, 64, 63, 63], [1, 1, 4, 4], [1, 1, 4, 4], 1, [0, 2, 4]),
     # widths are compared after rounding up to the lane multiple
     ([13, 16, 15, 14], [2] * 4, [2] * 4, 8, [0, 4]),
+    # one width and one K per half: one segment
+    ([8] * 6, [2] * 6, [3] * 6, 1, [0, 6]),
+    # a K that differs in one round rides along when it costs little
+    ([8] * 8, [4] * 7 + [3], [4] * 8, 1, [0, 8]),
 ])
 def test_segment_rule(widths, kf, kb, lm, want):
     assert segment_bounds(widths, kf, kb, lane_multiple=lm) == want
@@ -130,12 +199,18 @@ def test_segment_count_is_capped():
 # 2. The segmented apply is the substitution; one width is bitwise today's.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("batched", [False, True], ids=["single", "batched"])
-def test_segmented_apply_matches_sequential(batched):
-    sysd, l_bar = _system(20, 20)
+# the 16 x 16 grid's rounds share one width and are cut where K parts
+@pytest.mark.parametrize("batched, grid, segments", [
+    (False, 20, len(SEGMENTS_20)), (True, 20, len(SEGMENTS_20)),
+    (False, 16, 4), (True, 16, 4)],
+    ids=["single", "batched", "single-one-width", "batched-one-width"])
+def test_segmented_apply_matches_sequential(batched, grid, segments):
+    sysd, l_bar = _system(grid, grid)
     pre, lay = build_round_major_preconditioner_from_rounds(
         l_bar, sysd.fwd_rounds, sysd.bwd_rounds, drop_mask=sysd.drop)
-    assert pre.tables.n_segments == 3
+    assert pre.tables.n_segments == segments
+    if grid == 16:
+        assert {r for _, r in pre.tables.segments} == {16}
     rng = np.random.default_rng(0)
     r = rng.normal(size=(sysd.n_padded, 3) if batched else sysd.n_padded)
     if sysd.drop is not None:
@@ -152,12 +227,18 @@ def test_segmented_apply_matches_sequential(batched):
                                    atol=1e-11)
 
 
-@pytest.mark.parametrize("method", ["hbmc", "mc", "natural"])
-def test_one_width_is_one_segment_bitwise(method):
-    """Rounds of one width pack as one segment whose stacked halves are
-    the single-width fused tables, built here from the StepTables."""
-    sysd, l_bar = _system(16, 16, method)
-    fwd, bwd, fused = _fused(sysd, l_bar)
+@pytest.mark.parametrize("method, grid, max_segments", [
+    ("hbmc", (16, 16), 1), ("mc", (16, 16), 1), ("natural", (16, 16), 1),
+    # a chain in natural order: rounds of one lane and one K per half,
+    # which the cut itself leaves one segment
+    ("natural", (16, 1), MAX_SEGMENTS),
+], ids=["hbmc", "mc", "natural", "chain"])
+def test_one_width_is_one_segment_bitwise(method, grid, max_segments):
+    """One segment (the cut's, for rounds of one width and one K per half;
+    else ``max_segments=1``) stacks its halves into the single-width fused
+    tables, built here from the StepTables."""
+    sysd, l_bar = _system(*grid, method)
+    fwd, bwd, fused = _fused(sysd, l_bar, max_segments=max_segments)
     assert fused.n_segments == 1
     lay = round_major_layout(fwd)
     m = lay.m
@@ -205,9 +286,10 @@ def test_embed_extract_roundtrip_on_the_flat_state(nb):
     sysd, l_bar = _system(20, 20)
     _, _, fused = _fused(sysd, l_bar)
     lay = fused.layout
-    assert lay.m == sum(n * r for n, r in lay.segments) == 16 * 21 + 8 * 7 \
-        + 8 * 3
-    assert lay.offsets == (0, 16 * 21, 16 * 21 + 8 * 7)
+    assert lay.segments == SEGMENTS_20
+    assert lay.m == sum(n * r for n, r in lay.segments) == 403
+    assert lay.offsets == (0, 126, 186, 246, 306, 326, 333, 361, 373, 379,
+                           400)
     rng = np.random.default_rng(nb)
     v = rng.normal(size=(sysd.n_padded,) if nb == 1 else (sysd.n_padded, nb))
     if sysd.drop is not None:
@@ -222,23 +304,37 @@ def test_plan_counts_occupancy_over_the_layout():
     a = laplace_2d(20, 20)
     plan = build_plan(a, block_size=BS, w=W)
     live = np.count_nonzero(plan._rm.rows != plan._rm.n_slots - 1)
-    assert plan.n_segments == 3
+    n_seg = len(SEGMENTS_20)
+    assert plan.n_segments == n_seg
     assert plan.lane_occupancy == live / plan.slab_m
-    assert plan.slab_m == 16 * 21 + 8 * 7 + 8 * 3
+    assert plan.slab_m == 403
     b = np.random.default_rng(1).normal(size=a.shape[0])
     rep = plan.solve(b, rtol=1e-8)
-    assert (rep.n_segments, rep.lane_occupancy) == (3, plan.lane_occupancy)
+    assert (rep.n_segments, rep.lane_occupancy) == (n_seg,
+                                                    plan.lane_occupancy)
     rep_b = plan.solve_batched(b[:, None], rtol=1e-8)
-    assert (rep_b.n_segments, rep_b.lane_occupancy) == (3,
+    assert (rep_b.n_segments, rep_b.lane_occupancy) == (n_seg,
                                                         plan.lane_occupancy)
     assert rep.result.converged
     assert np.linalg.norm(a @ rep.x - b) < 1e-6 * np.linalg.norm(b)
+    # one width, cut only where K parts
     uniform = build_plan(laplace_2d(16, 16), block_size=BS, w=W)
-    assert (uniform.n_segments, uniform.lane_occupancy) == (1, 1.0)
+    assert (uniform.n_segments, uniform.lane_occupancy) == (4, 1.0)
     index = build_plan(a, block_size=BS, w=W, layout="index")
     assert index.n_segments == 1
     assert index.solve(b, rtol=1e-8).result.iterations == \
         rep.result.iterations
+
+
+def test_pallas_plan_stays_one_segment():
+    """The Pallas fused kernel takes uniform tables: its plan packs all
+    rounds as one segment where the XLA plan of the same grid cuts
+    eleven."""
+    plan = build_plan(laplace_2d(20, 20), block_size=BS, w=W,
+                      backend="pallas")
+    assert plan.n_segments == 1
+    assert plan._precond.tables.segments == ((32, 21),)
+    assert plan.sweep_steps == 2 * 32
 
 
 # ---------------------------------------------------------------------------

@@ -82,12 +82,17 @@ def test_sell_spmv_kernel_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# (rounds, lanes, forward K, backward K) of each lane-width segment: one
-# segment at the analogue's shapes, and the four of the heat2d benchmark
-# plan (525,625 rows, HBMC block 32, w 8), whose colors differ in width
+# (rounds, lanes, forward K, backward K) of each segment: one segment at
+# the analogue's shapes, the six of the heat2d benchmark plan (525,625
+# rows, HBMC block 32, w 8), whose colors differ in width and K, and the
+# four of the poisson2d benchmark plan (65,536 rows), one width cut where
+# K parts
 SEGMENTS = {"uniform": ((S_ROUNDS, R_XLA, K_TRI, K_TRI),),
-            "segmented": ((32, 7991, 2, 4), (32, 7909, 4, 3),
-                          (32, 434, 4, 3), (32, 99, 4, 1))}
+            "segmented": ((31, 7991, 1, 4), (3, 7991, 3, 3),
+                          (30, 7908, 4, 3), (32, 434, 4, 3),
+                          (31, 99, 3, 1), (1, 99, 4, 1)),
+            "poisson2d": ((1, 1024, 1, 4), (31, 1024, 1, 3),
+                          (31, 1024, 3, 1), (1, 1024, 4, 1))}
 
 
 def _sweep_tables(sharding, segs):
@@ -113,7 +118,8 @@ def test_xla_fused_sweep_compiles(one_chip, shape):
         tables, _spec(one_chip, (m,), jnp.float32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
-    # one loop per segment and half
+    # one loop per segment and half (with 64-bit loop counters a loop of
+    # one trip stays a loop)
     assert text.count(" while(") == 2 * len(segs)
 
 
@@ -156,20 +162,60 @@ def test_xla_ell_spmv_is_lane_dense(one_chip):
 
 
 
-@pytest.fixture(scope="module")
-def hpcg3d_plan():
-    """The hpcg3d benchmark plan: HPCG's 27-point operator on a 48^3 grid
-    (bench/configs/hpcg3d.py), eight segments of 502 lanes down to 1, K
-    up to 26 on the sublane axis of every sweep table."""
+def _bench_plan(relpath, fn, m):
+    """The benchmark's plan of the generator ``fn`` in ``relpath`` at
+    grid ``m`` (HBMC block 32, w 8)."""
     import importlib.util
     from pathlib import Path
 
     from repro.core import build_plan
-    path = Path(__file__).resolve().parents[1] / "bench/configs/hpcg3d.py"
-    spec = importlib.util.spec_from_file_location("hpcg3d_generator", path)
+    path = Path(__file__).resolve().parents[1] / relpath
+    spec = importlib.util.spec_from_file_location(f"bench_{fn}", path)
     gen = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(gen)
-    return build_plan(gen.hpcg_27pt(48), method="hbmc", block_size=32, w=8)
+    return build_plan(getattr(gen, fn)(m), method="hbmc", block_size=32,
+                      w=8)
+
+
+@pytest.fixture(scope="module")
+def hpcg3d_plan():
+    """The hpcg3d benchmark plan: HPCG's 27-point operator on a 48^3 grid
+    (bench/configs/hpcg3d.py), fourteen segments of 502 lanes down to 1,
+    K up to 26 on the sublane axis of every sweep table."""
+    return _bench_plan("bench/configs/hpcg3d.py", "hpcg_27pt", 48)
+
+
+@pytest.fixture(scope="module")
+def poisson2d_plan():
+    """The poisson2d benchmark plan: gallery('poisson', 256)
+    (bench/matrices.py), 64 rounds of 1,024 lanes in four segments, two of
+    them one round long."""
+    return _bench_plan("bench/matrices.py", "poisson_2d", 256)
+
+
+def _scratch_and_arguments(one_chip, plan, what, dtype):
+    """Compiled memory of the plan's sweep or whole PCG in ``dtype``."""
+    from repro.core.iccg import _pcg_device
+    from repro.core.plan import _make_spmv
+    from repro.core.trisolve import RoundMajorPreconditioner, fused_solve
+
+    host = plan._precond.tables
+    tables = jax.tree.map(lambda x: _spec(one_chip, x.shape, (
+        dtype if jnp.issubdtype(x.dtype, jnp.floating) else x.dtype)), host)
+    m, k_ell = host.m, plan._spmv_cols.shape[0]
+    q = _spec(one_chip, (m,), dtype)
+    if what == "sweep":
+        compiled = fused_solve.lower(tables, q).compile()
+    else:
+        def solve(tables, vals, cols, b):
+            spmv = _make_spmv("ell", m, vals, cols, False)
+            return _pcg_device(spmv, RoundMajorPreconditioner(tables), b,
+                               rtol=1e-7, maxiter=2000)
+
+        compiled = jax.jit(solve).lower(
+            tables, _spec(one_chip, (k_ell, m), dtype),
+            _spec(one_chip, (k_ell, m), jnp.int32), q).compile()
+    return compiled.memory_analysis()
 
 
 @pytest.mark.parametrize("what, dtype", [("pcg", jnp.float32),
@@ -183,29 +229,23 @@ def test_hpcg3d_shapes_keep_scratch_below_the_arguments(
     MB), because the chip's float64 emulation of the ELL SpMV's
     contraction holds 8 float32 planes of the (K, m) operand (504 MB
     against 113 MB here)."""
-    from repro.core.iccg import _pcg_device
-    from repro.core.plan import _make_spmv
-    from repro.core.trisolve import RoundMajorPreconditioner, fused_solve
-
-    plan = hpcg3d_plan
-    host = plan._precond.tables
+    host = hpcg3d_plan._precond.tables
     assert max(t.cols.shape[1] for t in host.fwd + host.bwd) == 26
-    assert [r for _, r in host.segments][-2:] == [23, 1]
-    tables = jax.tree.map(lambda x: _spec(one_chip, x.shape, (
-        dtype if jnp.issubdtype(x.dtype, jnp.floating) else x.dtype)), host)
-    m, k_ell = host.m, plan._spmv_cols.shape[0]
-    assert k_ell == 27
-    q = _spec(one_chip, (m,), dtype)
-    if what == "sweep":
-        compiled = fused_solve.lower(tables, q).compile()
-    else:
-        def solve(tables, vals, cols, b):
-            spmv = _make_spmv("ell", m, vals, cols, False)
-            return _pcg_device(spmv, RoundMajorPreconditioner(tables), b,
-                               rtol=1e-7, maxiter=2000)
+    assert [r for _, r in host.segments][-3:] == [23, 1, 1]
+    assert hpcg3d_plan._spmv_cols.shape[0] == 27
+    mem = _scratch_and_arguments(one_chip, hpcg3d_plan, what, dtype)
+    assert mem.temp_size_in_bytes < mem.argument_size_in_bytes, mem
 
-        compiled = jax.jit(solve).lower(
-            tables, _spec(one_chip, (k_ell, m), dtype),
-            _spec(one_chip, (k_ell, m), jnp.int32), q).compile()
-    mem = compiled.memory_analysis()
+
+@pytest.mark.parametrize("what, dtype", [("pcg", jnp.float32),
+                                         ("sweep", jnp.float64)])
+def test_poisson2d_shapes_keep_scratch_below_the_arguments(
+        one_chip, poisson2d_plan, what, dtype):
+    """The poisson2d plan's four loops a half, two of them one round long
+    and inlined, keep the sweep tables lane-dense inside the PCG loop:
+    scratch stays below the arguments, as for one segment (the float64
+    PCG is over them for its SpMV, as above)."""
+    host = poisson2d_plan._precond.tables
+    assert host.segments == ((1, 1024), (31, 1024), (31, 1024), (1, 1024))
+    mem = _scratch_and_arguments(one_chip, poisson2d_plan, what, dtype)
     assert mem.temp_size_in_bytes < mem.argument_size_in_bytes, mem
