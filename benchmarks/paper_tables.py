@@ -2,8 +2,8 @@
 
 Table 5.2  -> iterations_table():     #iterations MC / BMC / HBMC
 Table 5.3  -> trisolve_table():       sparse-triangular-solver + SpMV timing
-              (CPU-host analogue of the paper's per-node timings; the TPU
-              projection lives in the dry-run roofline)
+              (CPU-host analogue of the paper's per-node timings; TPU
+              times come from the chip benchmark in ``bench/``)
 Fig  5.1   -> convergence_overlay():  BMC vs HBMC residual histories
 §5.2.1     -> lane_occupancy_table(): vector-lane utilization (the SIMD-
               instruction-percentage analogue)

@@ -1,4 +1,4 @@
-"""Benchmark harness: one function per paper table + roofline summary.
+"""Benchmark harness: one function per paper table.
 
     PYTHONPATH=src python -m benchmarks.run [--scale small|bench]
 
@@ -8,7 +8,6 @@ human-readable tables.
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import jax
@@ -26,7 +25,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", default="small", choices=("tiny", "small",
                                                          "bench"))
-    ap.add_argument("--dryrun-dir", default="results/dryrun")
     args = ap.parse_args()
     from repro.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -102,14 +100,6 @@ def main() -> None:
     for name, occ, bmc_occ, ncol, nrounds in rows:
         print(f"{name:16s} {occ*100:6.1f}% {bmc_occ*100:6.1f}% "
               f"{ncol:7d} {nrounds:7d}")
-
-    # ---- Roofline summary from the dry-run -------------------------------
-    if os.path.isdir(args.dryrun_dir) and os.listdir(args.dryrun_dir):
-        from benchmarks.roofline_report import render_table
-        print("\n== Roofline (from multi-pod dry-run) ==")
-        print(render_table(args.dryrun_dir))
-        csv_rows.append(("roofline_cells", 0.0,
-                         f"{len(os.listdir(args.dryrun_dir))} cells"))
 
     print("\n--- CSV ---")
     print("name,us_per_call,derived")

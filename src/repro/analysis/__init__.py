@@ -16,8 +16,7 @@ Proves a plan is race-free and contract-conforming *before* it dispatches:
   ``traffic``        static bytes-per-iteration model cross-checked
                      against HLO-measured slice bytes, plus the
                      ``bench-gate`` snapshot regression gate
-  ``hlo``            the shared optimized-HLO parser + cost walker the
-                     above (and ``launch/``) build on
+  ``hlo``            the shared optimized-HLO parser the above build on
 
 ``build_plan(a, validate="cheap"|"full"|"deep")`` runs the detector at
 setup; ``python -m repro.analysis`` audits matrices/orderings/plans from
@@ -37,7 +36,7 @@ from .contracts import (DISTRIBUTED_APPLY, FULL_PALLAS_ITERATION,
 from .dtype_flow import (PrecisionContract, assert_plan_dtype_flow,
                          check_plan_dtype_flow, contract_for_plan,
                          lint_dtype_flow)
-from .hlo import CollectiveStats, analyze_hlo, parse_collectives
+from .hlo import CollectiveStats, parse_collectives
 from .kernel_checks import (VMEM_BUDGET_BYTES, assert_plan_kernels,
                             check_plan_kernels, check_sell_spmv,
                             check_trisolve_fused, sell_spmv_vmem_bytes,
@@ -64,7 +63,7 @@ __all__ = [
     "TrafficReport", "TrafficTerm", "assert_plan_traffic", "bench_gate",
     "check_plan_traffic", "compare_traffic", "measured_slice_bytes",
     "traffic_report",
-    "CollectiveStats", "analyze_hlo", "parse_collectives",
+    "CollectiveStats", "parse_collectives",
     "VMEM_BUDGET_BYTES", "assert_plan_kernels", "check_plan_kernels",
     "check_sell_spmv", "check_trisolve_fused", "sell_spmv_vmem_bytes",
     "trisolve_fused_vmem_bytes",

@@ -1,30 +1,13 @@
-"""Shared optimized-HLO parsing + trip-count-aware cost analysis.
+"""Shared optimized-HLO parsing for the analyzers in this package.
 
-One home for the HLO text machinery that used to be duplicated between
-``launch/hlo_analysis.py`` (full cost walker) and ``launch/roofline.py``
-(collective-only parser): the dtype/byte tables, the op/computation
-regexes, :func:`parse_module`, and the collective bookkeeping.  Both
-launch modules are now thin consumers, and the analyzers in this package
-(``collectives``, ``traffic``) build their structural proofs on the same
-parse.
+One home for the HLO text machinery that ``collectives`` and ``traffic``
+build their structural proofs on: the dtype/byte tables, the
+op/computation regexes, :func:`parse_module`, trip counts of while loops,
+and the collective bookkeeping.
 
 ``compiled.cost_analysis()`` counts a while-loop body ONCE, regardless of
-trip count — with scan-over-layers models that under-reports FLOPs/bytes by
-~n_layers and silently drops per-layer collectives.  :class:`Analyzer`
-walks the HLO computation graph instead:
-
-  * while ops multiply their body/condition cost by ``known_trip_count``
-    (XLA annotates scan/fori loops; dynamic whiles fall back to the bound
-    constant in the condition, else 1);
-  * fusion/call/conditional recurse into called computations (FLOPs), while
-    HBM traffic is charged at the fusion boundary (operands + result), the
-    same model XLA's own analysis uses;
-  * collectives are recorded by kind with the loop multiplier applied, so a
-    per-layer all-reduce inside the layer scan is counted n_layers times.
-
-FLOP model: dot = 2 * result_elems * contraction_size; elementwise-ish ops =
-1 flop/output element; reduce = input elems.  Conservative and dominated by
-dots for every cell we lower.
+trip count, so the analyzers walk the parsed computation graph instead and
+multiply each loop body by :func:`trip_count`.
 
 Everything here is pure-python text processing: no jax import.
 """
@@ -32,7 +15,6 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from collections import defaultdict
 
 _DTYPE_BYTES = {
     "pred": 1, "s2": 1, "u2": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -51,33 +33,6 @@ _OP_RE = re.compile(
     r"(?P<op>[\w\-]+)\((?P<args>.*?)\)(?P<rest>.*)$")
 
 _COMP_RE = re.compile(r"^(ENTRY\s+)?%?(?P<name>[\w\.\-]+)\s*\(.*\)\s*->.*{\s*$")
-
-_ELEMENTWISE = {
-    "add", "subtract", "multiply", "divide", "power", "maximum", "minimum",
-    "tanh", "exponential", "log", "rsqrt", "sqrt", "negate", "abs", "sign",
-    "floor", "ceil", "round-nearest-afz", "round-nearest-even", "logistic",
-    "cosine", "sine", "atan2", "select", "compare", "and", "or", "xor",
-    "not", "clamp", "convert", "erf", "exponential-minus-one", "log-plus-one",
-    "remainder", "shift-left", "shift-right-logical", "shift-right-arithmetic",
-    "cbrt", "is-finite", "stochastic-convert",
-}
-
-_MEMORY_OPS = {
-    "fusion", "dot", "convolution", "copy", "dynamic-slice",
-    "dynamic-update-slice", "gather", "scatter", "sort", "transpose",
-    "reduce", "broadcast", "concatenate", "pad", "slice", "reverse", "map",
-    "reduce-window", "select-and-scatter", "iota", "rng", "cholesky",
-    "triangular-solve", "all-reduce", "all-gather", "reduce-scatter",
-    "all-to-all", "collective-permute",
-}
-
-# TPU-faithful HBM model: ops a TPU backend materializes for free
-_ZERO_COST = {"broadcast", "iota", "constant", "reshape", "bitcast",
-              "tuple", "get-tuple-element", "parameter", "after-all",
-              "partition-id", "replica-id", "optimization-barrier"}
-# producers/consumers that TPU fusion merges (intermediate never hits HBM)
-_FUSABLE = _ELEMENTWISE | {"fusion", "dot", "convolution", "reduce",
-                           "transpose", "map"}
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")
@@ -119,7 +74,6 @@ class Op:
     type_str: str
     args: list
     rest: str
-    elems: int
     bytes: int
 
 
@@ -128,7 +82,6 @@ class Computation:
     name: str
     ops: list
     by_name: dict
-    use_count: dict = dataclasses.field(default_factory=dict)
 
 
 def parse_module(text: str) -> dict:
@@ -147,23 +100,13 @@ def parse_module(text: str) -> dict:
         m = _OP_RE.match(line)
         if not m:
             continue
-        elems, byts = shape_info(m.group("type"))
+        _, byts = shape_info(m.group("type"))
         args = [a.strip().lstrip("%") for a in
                 _split_args(m.group("args"))]
         op = Op(m.group("name"), m.group("op"), m.group("type"), args,
-                m.group("rest"), elems, byts)
+                m.group("rest"), byts)
         cur.ops.append(op)
         cur.by_name[op.name] = op
-    for comp in comps.values():
-        uc: dict = {}
-        consumers: dict = {}
-        for op in comp.ops:
-            for a in op.args:
-                name = a.split()[-1].lstrip("%")
-                uc[name] = uc.get(name, 0) + 1
-                consumers.setdefault(name, []).append(op.kind)
-        comp.use_count = uc
-        comp.consumers = consumers          # type: ignore[attr-defined]
     return comps
 
 
@@ -194,7 +137,6 @@ def _split_args(s: str) -> list:
 _TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 _CALL_RE = re.compile(r"(?:calls|to_apply|body|condition|branch_computations)="
                       r"(\{[^}]*\}|%?[\w\.\-]+)")
-_CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 _GROUPS_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
 
 
@@ -258,192 +200,10 @@ def _arg_op(op: Op, comp: Computation, i: int):
     return comp.by_name.get(op.args[i].split()[-1].lstrip("%"))
 
 
-def _bf16_rooted(op, comp: Computation, depth: int = 4) -> bool:
-    """True if this f32 value is (transitively) produced from bf16 data —
-    i.e. it exists in f32 only because XLA:CPU expands bf16 dots to f32.
-    Conservative DFS: unresolvable chains (loop carries, parameters) count
-    as NOT bf16-rooted."""
-    if op is None or depth <= 0:
-        return False
-    if "bf16[" in op.type_str:
-        return True
-    if op.kind == "convert" or (op.kind == "fusion"
-                                and "convert" in op.name):
-        inner = _arg_op(op, comp, 0)
-        return inner is not None and "bf16[" in inner.type_str
-    if op.kind in ("dot", "add", "multiply", "subtract", "maximum",
-                   "minimum", "copy", "transpose", "reshape", "bitcast",
-                   "fusion", "divide", "exponential", "tanh", "select"):
-        args = [_arg_op(op, comp, i) for i in range(len(op.args))]
-        args = [a for a in args if a is not None and a.kind != "constant"
-                and not a.type_str.startswith(("s32", "u32", "pred"))]
-        if not args:
-            return False
-        return all(_bf16_rooted(a, comp, depth - 1) for a in args)
-    return False
-
-
-def _hbm_bytes(op: Op, comp: Computation, base: str) -> float:
-    """TPU-faithful HBM traffic for one op, with fusion-chain coalescing:
-    a single-use intermediate between two fusable ops never hits HBM."""
-    if base in _ZERO_COST:
-        return 0.0
-    if base == "dynamic-slice":
-        return 2.0 * op.bytes                      # read slice + write
-    if base == "gather":
-        return 2.0 * op.bytes                      # random reads ~ result
-    if base == "dynamic-update-slice":
-        upd = _arg_op(op, comp, 1)
-        b = upd.bytes if upd is not None else op.bytes
-        return 2.0 * b                             # in-place slice update
-    if base == "scatter":
-        upd = _arg_op(op, comp, 2)
-        b = upd.bytes if upd is not None else op.bytes
-        return 3.0 * b                             # read+modify+write
-    if base in ("copy", "concatenate", "pad", "slice", "reverse"):
-        return 2.0 * op.bytes
-    if base == "sort":
-        return 2.0 * (op.bytes + operand_bytes(op, comp))
-
-    # fusable family (elementwise / fusion / dot / reduce / transpose):
-    # charge operands whose producer is NOT a single-use fusable op, and
-    # the result only if some consumer is non-fusable or it is multi-use.
-    total = 0.0
-    for a in op.args:
-        name = a.split()[-1].lstrip("%")
-        ref = comp.by_name.get(name)
-        if ref is None:
-            continue
-        ref_base = base_kind(ref.kind)
-        if ref_base in _ZERO_COST:
-            continue
-        if ref_base in _FUSABLE and comp.use_count.get(name, 0) == 1:
-            continue                               # fused edge: free
-        total += ref.bytes
-    cons = getattr(comp, "consumers", {}).get(op.name, [])
-    fused_out = (len(cons) == 1 and cons[0] in _FUSABLE
-                 and base in _FUSABLE)
-    if not fused_out:
-        total += op.bytes
-    return total
-
-
-class Analyzer:
-    def __init__(self, text: str):
-        self.comps = parse_module(text)
-        self._memo: dict = {}
-        self.entry = entry_name(text, self.comps)
-
-    def _trip_count(self, op: Op) -> int:
-        return trip_count(op, self.comps)
-
-    def comp_cost(self, name: str) -> dict:
-        if name in self._memo:
-            return self._memo[name]
-        comp = self.comps.get(name)
-        zero = {"flops": 0.0, "bytes": 0.0,
-                "coll_bytes": defaultdict(float),
-                "coll_count": defaultdict(float),
-                "coll_wire": 0.0}
-        if comp is None:
-            return zero
-        self._memo[name] = zero  # break cycles defensively
-        flops = byts = wire = 0.0
-        coll_b: defaultdict = defaultdict(float)
-        coll_c: defaultdict = defaultdict(float)
-
-        for op in comp.ops:
-            kind = op.kind
-            base = base_kind(kind)
-            if kind.endswith("-done") or kind.endswith("-update-done"):
-                continue
-            if base == "while":
-                trip = self._trip_count(op)
-                for cname in called_comps(op.rest):
-                    sub = self.comp_cost(cname)
-                    flops += trip * sub["flops"]
-                    byts += trip * sub["bytes"]
-                    wire += trip * sub["coll_wire"]
-                    for k, v in sub["coll_bytes"].items():
-                        coll_b[k] += trip * v
-                    for k, v in sub["coll_count"].items():
-                        coll_c[k] += trip * v
-                continue
-            if base in ("fusion", "call", "conditional", "async-start"):
-                for cname in called_comps(op.rest):
-                    sub = self.comp_cost(cname)
-                    flops += sub["flops"]
-                    byts += sub["bytes"]
-                    wire += sub["coll_wire"]
-                    for k, v in sub["coll_bytes"].items():
-                        coll_b[k] += v
-                    for k, v in sub["coll_count"].items():
-                        coll_c[k] += v
-                if base == "fusion":
-                    byts += _hbm_bytes(op, comp, base)
-                continue
-            if base == "dot":
-                contract = 1
-                m = _CONTRACT_RE.search(op.rest)
-                if m and op.args:
-                    lhs = comp.by_name.get(op.args[0].split()[-1].lstrip("%"))
-                    if lhs is not None:
-                        shp = _SHAPE_RE.search(lhs.type_str)
-                        if shp:
-                            dims = [int(d) for d in shp.group(2).split(",")
-                                    if d]
-                            for di in (int(x) for x in m.group(1).split(",")
-                                       if x):
-                                if di < len(dims):
-                                    contract *= dims[di]
-                flops += 2.0 * op.elems * contract
-                byts += _hbm_bytes(op, comp, base)
-                continue
-            if base in COLLECTIVES:
-                buf = max(op.bytes, operand_bytes(op, comp))
-                # CPU-backend correction: XLA CPU expands bf16 dots to f32,
-                # so the partitioner moves f32 buffers where TPU would move
-                # bf16.  A collective whose operands are (chains of)
-                # converts from bf16 is charged at bf16 width.
-                if "f32[" in op.type_str and op.args and all(
-                        _bf16_rooted(_arg_op(op, comp, i_), comp)
-                        for i_ in range(len(op.args))):
-                    buf *= 0.5
-                coll_b[base] += buf
-                coll_c[base] += 1
-                wire += COLL_WIRE[base] * buf
-                byts += op.bytes + operand_bytes(op, comp)
-                continue
-            if base in _ELEMENTWISE:
-                flops += op.elems
-            elif base == "reduce":
-                flops += operand_bytes(op, comp) // 4 or op.elems
-            byts += _hbm_bytes(op, comp, base)
-
-        out = {"flops": flops, "bytes": byts, "coll_bytes": coll_b,
-               "coll_count": coll_c, "coll_wire": wire}
-        self._memo[name] = out
-        return out
-
-    def totals(self) -> dict:
-        t = self.comp_cost(self.entry)
-        return {
-            "flops": t["flops"],
-            "bytes": t["bytes"],
-            "collective_wire_bytes": t["coll_wire"],
-            "collective_bytes_by_kind": dict(t["coll_bytes"]),
-            "collective_counts": dict(t["coll_count"]),
-        }
-
-
-def analyze_hlo(text: str) -> dict:
-    return Analyzer(text).totals()
-
-
 @dataclasses.dataclass
 class CollectiveStats:
     """Module-wide collective census (counts are STATIC op counts — no loop
-    trip multiplication; use :class:`Analyzer` for executed counts)."""
+    trip multiplication)."""
     bytes_by_kind: dict
     count_by_kind: dict
 

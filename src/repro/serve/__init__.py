@@ -1,8 +1,5 @@
-"""Serving layers: the solver service (solver.py) and LM steps (step.py).
-
-``step`` is not imported here — it pulls in ``repro.models``; import it
-explicitly (``from repro.serve import step``) when needed.
-"""
+"""Serving layer: the solver service (solver.py) and its seeded fault
+injection (faults.py)."""
 from .faults import FAULT_KINDS, FaultInjector, FaultPlan
 from .solver import (DEFAULT_COSTS, SERVICE_STATUSES, CacheStats, Completed,
                      PlanBusyError, PlanCache, PlanKey, QueueFullError,

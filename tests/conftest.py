@@ -6,7 +6,6 @@ import pytest
 
 # float64 is required for the solver-equivalence guarantees (the paper's
 # Table 5.2 iteration counts are only bitwise-stable in double precision).
-# Model tests pass explicit f32 dtypes, unaffected by this flag.
 jax.config.update("jax_enable_x64", True)
 
 try:
